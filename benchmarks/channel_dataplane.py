@@ -20,7 +20,8 @@ benchmark times exactly that primitive on the social dataset stand-in:
     over one worker's edge array) via the jnp reference vs the Pallas
     kernel with the plan's autotuned block sizes. On CPU the kernel runs
     in interpret mode — a correctness vehicle, recorded for the record,
-    not a race it can win; on TPU it is the default path.
+    not a race it can win; on TPU it is the default path, compiled for
+    the chip (both kernels compile for v5e: tests/test_tpu_compile.py).
 
 Results go to ``BENCH_channel_dataplane.json``; the ``headline`` block
 records the bucket-vs-sort speedup at the largest benched scale (the
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
+from repro import compile_cache
 from repro.core import routing
 from repro.core.channel import ChannelContext
 from repro.kernels import ops as kops
@@ -158,6 +160,7 @@ def run_and_write(scales, repeats: int = 5,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scales", type=int, nargs="+",
                     default=[10, 11, 12, 13, 14])

@@ -31,6 +31,7 @@ import argparse
 import json
 
 from benchmarks import common
+from repro import compile_cache
 from repro.algorithms import REGISTRY
 from repro.graph import pgraph
 from repro.pregel.engine import Engine
@@ -177,6 +178,7 @@ def run_and_write(scale: int, out_path: str = "BENCH_paper_tables.json"):
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=12)
     ap.add_argument("--out", default="BENCH_paper_tables.json")

@@ -33,6 +33,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.algorithms import REGISTRY
 from repro.graph import pgraph
 from repro.pregel.engine import Engine
@@ -157,6 +158,7 @@ def run_and_write(scales, repeats: int = 3, keys=DEFAULT_KEYS,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scales", type=int, nargs="+", default=None)
     ap.add_argument("--scale", type=int, default=None,

@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.algorithms import REGISTRY
 from repro.graph import pgraph
 from repro.pregel import checkpoint as ckpt_io
@@ -233,6 +234,7 @@ def run_and_write(scale: int = 10, seed: int = 0,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)

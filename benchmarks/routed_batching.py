@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.algorithms import REGISTRY
 from repro.graph import pgraph
 from repro.pregel.engine import Engine
@@ -145,6 +146,7 @@ def run_and_write(scale: int = 12, q: int = 32, repeats: int = 3,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=12)
     ap.add_argument("--queries", type=int, default=32)

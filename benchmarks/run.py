@@ -15,6 +15,9 @@ import sys
 
 def main() -> None:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from repro import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=None)
     ap.add_argument("--full", action="store_true")

@@ -24,6 +24,7 @@ import statistics
 
 import numpy as np
 
+from repro import compile_cache
 from repro.algorithms import pointer_jumping
 from repro.graph import generators as gen, pgraph
 
@@ -94,6 +95,7 @@ def run_and_write(scale: int = 14, repeats: int = 5, chunk_size: int = 8,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=14)
     ap.add_argument("--repeats", type=int, default=5)

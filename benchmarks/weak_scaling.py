@@ -150,6 +150,9 @@ def main() -> int:
     args = ap.parse_args()
 
     if args.child:
+        from repro import compile_cache
+
+        compile_cache.enable()
         child(int(args.devices), args.scale, args.repeats, args.seed)
         return 0
 

@@ -47,6 +47,7 @@ import json
 import sys
 import time
 
+from repro import compile_cache
 from repro.algorithms import (ALGORITHMS, BATCHED, DEFAULT_VARIANT, REGISTRY,
                               resolve)
 from repro.graph import partition as partition_lib
@@ -498,6 +499,7 @@ def main(argv=None) -> int:
     p_plan.set_defaults(fn=cmd_plan)
 
     args = ap.parse_args(argv)
+    compile_cache.enable()
     return args.fn(args)
 
 

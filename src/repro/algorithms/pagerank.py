@@ -19,6 +19,8 @@ one-shot wrapper over :class:`repro.pregel.engine.Engine`.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from repro.core import aggregator as agg
@@ -33,7 +35,7 @@ VARIANTS = ("basic", "scatter", "personal")
 
 def program(variant: str = "scatter", *, iters: int = 30,
             damping: float = 0.85, source: int = 0,
-            use_kernel: bool = False) -> VertexProgram:
+            use_kernel: Optional[bool] = None) -> VertexProgram:
     """PageRank as a VertexProgram. Output: (n,) ranks in old-id space."""
     if variant not in VARIANTS:
         raise ValueError(variant)
@@ -87,7 +89,7 @@ def program(variant: str = "scatter", *, iters: int = 30,
 
 
 def _personal(*, iters: int, damping: float, source: int,
-              use_kernel: bool) -> VertexProgram:
+              use_kernel: Optional[bool]) -> VertexProgram:
     """Personalized PageRank: teleport and sink mass concentrate on one
     source vertex. The source rides the *state* as a per-worker scalar
     (not a closure constant), so the step stays graph- and
@@ -135,7 +137,8 @@ def _personal(*, iters: int, damping: float, source: int,
 
 def run(pg: PartitionedGraph, iters: int = 30, variant: str = "scatter",
         damping: float = 0.85, source: int = 0, backend: str = "vmap",
-        mesh=None, use_kernel: bool = False, mode=None, chunk_size: int = 64):
+        mesh=None, use_kernel: Optional[bool] = None, mode=None,
+        chunk_size: int = 64):
     prog = program(variant=variant, iters=iters, damping=damping,
                    source=source, use_kernel=use_kernel)
     res = engine.run_program(prog, pg, backend=backend, mesh=mesh, mode=mode,

@@ -39,6 +39,8 @@ one-shot wrapper over :class:`repro.pregel.engine.Engine`.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from repro.algorithms import common
@@ -55,7 +57,7 @@ INF32 = jnp.iinfo(jnp.int32).max
 VARIANTS = ("basic", "reqresp", "scatter", "both", "monolithic", "composed")
 
 
-def composed_channels(use_kernel: bool = False) -> compose.Stacked:
+def composed_channels(use_kernel: Optional[bool] = None) -> compose.Stacked:
     """The §V composition: the three optimized channels plus full jumping,
     stacked under the ``sv/`` namespace with per-component attribution."""
 
@@ -117,7 +119,7 @@ def _extract(pg, state):
 
 
 def program(variant: str = "both", *, max_steps: int = 200,
-            use_kernel: bool = False) -> VertexProgram:
+            use_kernel: Optional[bool] = None) -> VertexProgram:
     """S-V as a VertexProgram. Output: (n,) component labels (min member
     id) in old-id space."""
     if variant not in VARIANTS:
@@ -218,7 +220,8 @@ def program(variant: str = "both", *, max_steps: int = 200,
 
 
 def run(pg: PartitionedGraph, variant: str = "both", max_steps: int = 200,
-        backend: str = "vmap", mesh=None, use_kernel: bool = False,
+        backend: str = "vmap", mesh=None,
+        use_kernel: Optional[bool] = None,
         mode=None, chunk_size: int = 64, route_impl=None):
     prog = program(variant=variant, max_steps=max_steps,
                    use_kernel=use_kernel)

@@ -8,87 +8,91 @@ the O(M log M) ``argsort`` it replaces — and maps onto the TPU as a
 single sequential sweep over message chunks:
 
   - the message keys (owner per message, already clipped; ``B`` = invalid
-    sentinel) are tiled into chunks of ``block_msgs``,
-  - a ``(B + 1,)`` running-occupancy vector lives in the revisited counts
+    sentinel) are tiled into chunks of ``block_msgs``, each laid out
+    lane-dense as ``(rows, lanes)`` in message order (``lanes`` =
+    ``min(block_msgs, 128)``), so no key is padded out to a lane row,
+  - a ``(1, B + 1)`` running-occupancy row lives in the revisited counts
     output (the canonical Pallas accumulator pattern: initialized at grid
     step 0, read-modify-written by every step),
-  - inside a chunk the per-bucket arrival ranks are a one-hot
-    ``jnp.cumsum`` on the VPU (buckets are the worker count — a few
-    lanes), offset by the running occupancy carried in from the previous
-    chunks.
+  - inside a chunk, per bucket, a message's arrival rank is the count of
+    the bucket's earlier messages in its own row — an inclusive prefix
+    along the lanes, taken as one matmul with an upper-triangular ones
+    matrix on the MXU (0/1 operands, float32 accumulation: exact) — plus
+    the bucket's count in the earlier rows, plus the occupancy carried in
+    from the previous chunks.
 
 Grid: ``(num_chunks,)``, iterated sequentially on one core — exactly the
-property that makes the running counts carry correct.  The actual scatter
+property that makes the running counts carry correct. The actual scatter
 into the ``(W, C, ...)`` send buffer stays outside the kernel (a plain
 ``.at[slot].set``): the expensive part of the routing was never the
 scatter, it was computing the permutation.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import chunk_tile, col_to_row
 
-def _kernel(key_ref, rank_ref, counts_ref, *, num_buckets):
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+def _chunk_ranks(keys, counts_ref, per_bucket=None):
+    """Stable ranks of one ``(R, L)`` key chunk; advances the running
+    occupancy row and calls ``per_bucket(b, onehot)`` for each bucket."""
+    r, l = keys.shape
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+             ).astype(jnp.float32)
+    before = (jax.lax.broadcasted_iota(jnp.int32, (r, r), 1)
+              < jax.lax.broadcasted_iota(jnp.int32, (r, r), 0))
+    rank = jnp.zeros((r, l), jnp.int32)
+    for b in range(counts_ref.shape[1]):
+        hit = keys == b
+        onehot = hit.astype(jnp.float32)
+        # inclusive count of bucket b along each row, on the MXU
+        in_row = jnp.dot(onehot, upper, preferred_element_type=jnp.float32)
+        row_tot = jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+        # bucket b's count in all earlier rows of the chunk
+        earlier = jnp.sum(jnp.where(before, col_to_row(row_tot), 0),
+                          axis=1, keepdims=True)
+        base = counts_ref[:, b:b + 1]
+        rank = jnp.where(hit, in_row.astype(jnp.int32) - 1 + earlier + base,
+                         rank)
+        counts_ref[:, b:b + 1] = base + jnp.sum(row_tot, keepdims=True)
+        if per_bucket is not None:
+            per_bucket(b, onehot)
+    return rank
+
+
+def _kernel(key_ref, rank_ref, counts_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    keys = key_ref[:, 0]  # (BM,) bucket id per message, B = invalid
-    cols = jax.lax.broadcasted_iota(
-        jnp.int32, (keys.shape[0], num_buckets + 1), 1
-    )
-    onehot = (keys[:, None] == cols).astype(jnp.int32)  # (BM, B+1)
-    base = counts_ref[0, :]  # (B+1,) occupancy before this chunk
-    within = jnp.cumsum(onehot, axis=0) - 1  # arrival rank inside the chunk
-    # one-hot rows are exact selectors: sum picks rank for this key only
-    rank = jnp.sum(onehot * (within + base[None, :]), axis=1)
-    rank_ref[:, 0] = rank
-    counts_ref[0, :] = base + onehot.sum(axis=0)
+    rank_ref[...] = _chunk_ranks(key_ref[...], counts_ref)
 
 
-def _kernel_lanes(key_ref, lane_ref, rank_ref, counts_ref, lane_counts_ref,
-                  *, num_buckets):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+def _kernel_lanes(key_ref, lane_ref, rank_ref, counts_ref, lane_counts_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
         lane_counts_ref[...] = jnp.zeros_like(lane_counts_ref)
 
-    keys = key_ref[:, 0]
-    cols = jax.lax.broadcasted_iota(
-        jnp.int32, (keys.shape[0], num_buckets + 1), 1
-    )
-    onehot = (keys[:, None] == cols).astype(jnp.int32)  # (BM, B+1)
-    base = counts_ref[0, :]
-    within = jnp.cumsum(onehot, axis=0) - 1
-    rank = jnp.sum(onehot * (within + base[None, :]), axis=1)
-    rank_ref[:, 0] = rank
-    counts_ref[0, :] = base + onehot.sum(axis=0)
-    # per-lane per-bucket histogram delta for this chunk: one
-    # (B+1, BM) x (BM, Q) contraction — an MXU matmul on TPU. float32
-    # accumulation is exact here (counts are bounded by M << 2^24).
-    lanes = lane_ref[...]  # (BM, Q) membership
-    delta = jax.lax.dot_general(
-        onehot.astype(jnp.float32),
-        lanes.astype(jnp.float32),
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    lane_counts_ref[...] = lane_counts_ref[...] + delta.astype(jnp.int32)
+    def lane_histogram(b, onehot):
+        # per-lane count of bucket b over this chunk
+        for q in range(lane_ref.shape[0]):
+            member = lane_ref[q].astype(jnp.float32)  # (R, L) 0/1
+            n = jnp.sum(onehot * member, keepdims=True).astype(jnp.int32)
+            lane_counts_ref[b:b + 1, q:q + 1] += n
+
+    rank_ref[...] = _chunk_ranks(key_ref[...], counts_ref, lane_histogram)
 
 
 def bucket_ranks_pallas(
     keys,
     *,
     num_buckets: int,
-    block_msgs: int = 512,
+    block_msgs: int = 1024,
     interpret: bool = True,
 ):
     """Stable per-bucket arrival ranks via a sequential counting sweep.
@@ -99,30 +103,30 @@ def bucket_ranks_pallas(
         tails are harmless). ``M_pad`` must be a multiple of
         ``block_msgs``.
       num_buckets: static bucket count B (the worker count).
-      block_msgs: chunk length per grid step.
+      block_msgs: chunk length per grid step (at most 128, or a multiple
+        of 128).
     Returns:
       (rank, counts): (M_pad,) int32 stable rank within bucket and the
       (B + 1,) final occupancy histogram (sentinel bucket last).
     """
     m = keys.shape[0]
     assert m % block_msgs == 0, (m, block_msgs)
-    grid = (m // block_msgs,)
-    kernel = functools.partial(_kernel, num_buckets=num_buckets)
+    r, l = chunk_tile(block_msgs)
+    nc = m // block_msgs
+    tile = pl.BlockSpec((pl.squeezed, r, l), lambda i: (i, 0, 0))
     rank, counts = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_msgs, 1), lambda i: (i, 0))],
-        out_specs=(
-            pl.BlockSpec((block_msgs, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_buckets + 1), lambda i: (0, 0)),
-        ),
+        _kernel,
+        grid=(nc,),
+        in_specs=[tile],
+        out_specs=(tile,
+                   pl.BlockSpec((1, num_buckets + 1), lambda i: (0, 0))),
         out_shape=(
-            jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nc, r, l), jnp.int32),
             jax.ShapeDtypeStruct((1, num_buckets + 1), jnp.int32),
         ),
         interpret=interpret,
-    )(jnp.asarray(keys, jnp.int32)[:, None])
-    return rank[:, 0], counts[0]
+    )(jnp.asarray(keys, jnp.int32).reshape(nc, r, l))
+    return rank.reshape(m), counts[0]
 
 
 def bucket_ranks_lanes_pallas(
@@ -130,7 +134,7 @@ def bucket_ranks_lanes_pallas(
     lanes,
     *,
     num_buckets: int,
-    block_msgs: int = 512,
+    block_msgs: int = 1024,
     interpret: bool = True,
 ):
     """Q-aware bucket ranking: the same sequential counting sweep as
@@ -143,7 +147,7 @@ def bucket_ranks_lanes_pallas(
         (``num_buckets`` = invalid sentinel); M_pad a ``block_msgs``
         multiple.
       lanes: (M_pad, Q) int32 lane membership (0/1); padded tail rows
-        must be all-zero.
+        must be all-zero. The kernel reads it query-major, lane-dense.
     Returns:
       (rank (M_pad,), counts (B + 1,), lane_counts (B + 1, Q)).
     """
@@ -151,25 +155,29 @@ def bucket_ranks_lanes_pallas(
     q = lanes.shape[1]
     assert m % block_msgs == 0, (m, block_msgs)
     assert lanes.shape[0] == m, (lanes.shape, m)
-    grid = (m // block_msgs,)
-    kernel = functools.partial(_kernel_lanes, num_buckets=num_buckets)
+    r, l = chunk_tile(block_msgs)
+    nc = m // block_msgs
+    tile = pl.BlockSpec((pl.squeezed, r, l), lambda i: (i, 0, 0))
     rank, counts, lane_counts = pl.pallas_call(
-        kernel,
-        grid=grid,
+        _kernel_lanes,
+        grid=(nc,),
         in_specs=[
-            pl.BlockSpec((block_msgs, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_msgs, q), lambda i: (i, 0)),
+            tile,
+            pl.BlockSpec((q, pl.squeezed, r, l), lambda i: (0, i, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((block_msgs, 1), lambda i: (i, 0)),
+            tile,
             pl.BlockSpec((1, num_buckets + 1), lambda i: (0, 0)),
             pl.BlockSpec((num_buckets + 1, q), lambda i: (0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nc, r, l), jnp.int32),
             jax.ShapeDtypeStruct((1, num_buckets + 1), jnp.int32),
             jax.ShapeDtypeStruct((num_buckets + 1, q), jnp.int32),
         ),
         interpret=interpret,
-    )(jnp.asarray(keys, jnp.int32)[:, None], jnp.asarray(lanes, jnp.int32))
-    return rank[:, 0], counts[0], lane_counts
+    )(
+        jnp.asarray(keys, jnp.int32).reshape(nc, r, l),
+        jnp.asarray(lanes, jnp.int32).T.reshape(q, nc, r, l),
+    )
+    return rank.reshape(m), counts[0], lane_counts

@@ -15,9 +15,10 @@ wins:
   2. the :func:`use_kernel_scope` context (how ``Engine(use_kernel=...)``
      threads the knob through a compile);
   3. the ``REPRO_USE_KERNEL`` environment variable (``1/true/yes/on``);
-  4. the backend default: **on** for TPU (the kernels are the fast path
-     there), off elsewhere (the interpret-mode kernel is a correctness
-     vehicle on CPU, not a fast path).
+  4. the backend default: **on** for TPU (the kernels are lowered for
+     the chip there, never interpreted), off elsewhere (the
+     interpret-mode kernel is a correctness vehicle on CPU, not a fast
+     path).
 """
 from __future__ import annotations
 
@@ -218,7 +219,7 @@ def bucket_ranks(
     *,
     use_kernel: Optional[bool] = None,
     interpret: Optional[bool] = None,
-    block_msgs: int = 512,
+    block_msgs: int = 1024,
 ):
     """Stable arrival rank of each message within its bucket, plus the
     per-bucket occupancy — the permutation core of the one-pass routed
@@ -256,7 +257,7 @@ def bucket_ranks_lanes(
     *,
     use_kernel: Optional[bool] = None,
     interpret: Optional[bool] = None,
-    block_msgs: int = 512,
+    block_msgs: int = 1024,
 ):
     """Q-aware bucket ranking for the union-frontier batched data plane:
     shared stable ranks over the union key list plus the per-lane

@@ -5,19 +5,23 @@ per-superstep combine is a linear scan instead of hash routing. On TPU the
 same preprocessing yields a *block-CSR segment reduction*:
 
   - destination rows are tiled into blocks of ``block_rows`` (the output
-    VMEM tile),
+    tile, written lane-dense as one ``(D, block_rows)`` row per block),
   - the edge array (values + segment ids, already sorted by segment) is
-    tiled into chunks of ``block_edges``,
+    tiled into chunks of ``block_edges``, each laid out lane-dense as
+    ``(rows, lanes)`` in edge order (``repro.kernels.layout``),
   - a host-side plan maps each row block to its covering chunk range
     (scalar-prefetched, the standard block-sparse index-table pattern),
-  - inside the kernel each chunk is reduced with a segmented Hillis-Steele
-    scan (log2(block_edges) steps on the VPU) and the per-segment partials
-    are scattered into the output tile with a one-hot ``dot_general`` on
-    the MXU.
+  - inside the kernel each edge row of a chunk is reduced by a masked
+    select and a lane reduction: the ``(block_rows, lanes)`` mask
+    ``seg == row`` picks each output row's edges, everything else holds
+    the identity, and one ``sum``/``min``/``max`` along the lanes leaves
+    the row's partial.
 
-Works for sum/min/max (any Combiner with an identity): each chunk emits at
-most one partial per row ("segment end", with a virtual end at the chunk
-boundary), and partials combine across chunks with the same combiner.
+Works for the ``sum``, ``min`` and ``max`` combiners in any dtype, with
+no integer matmul and no narrowing reshape, so it lowers on every TPU
+generation. Partials combine across chunks with the same combiner: for
+``min``/``max`` and integer ``sum`` the result is exactly the
+reference's, and a float ``sum`` differs from it in rounding order only.
 
 Grid: (num_row_blocks, max_chunks_per_block); the output tile is revisited
 across the chunk axis and initialized at chunk 0 — the canonical Pallas
@@ -30,34 +34,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import combiners as cb
+from repro.kernels.layout import chunk_tile, col_to_row
+
+#: lane reduction per supported combiner (the kernel's whole menu)
+_REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
 
 
-def _segmented_scan(vals, seg, combiner, ident):
-    """Inclusive Hillis-Steele scan of `vals` within equal-`seg` runs."""
-    n = vals.shape[0]
-    shift = 1
-    while shift < n:
-        prev_v = jnp.concatenate(
-            [jnp.full((shift,) + vals.shape[1:], ident, vals.dtype), vals[:-shift]], 0
-        )
-        prev_s = jnp.concatenate(
-            [jnp.full((shift,), -1, seg.dtype), seg[:-shift]], 0
-        )
-        same = (prev_s == seg)[:, None]
-        vals = jnp.where(same, combiner(vals, prev_v), vals)
-        shift *= 2
-    return vals
-
-
-def _kernel(cs_ref, nc_ref, seg_ref, vals_ref, o_ref, *, combiner, block_rows):
+def _kernel(cs_ref, nc_ref, seg_ref, vals_ref, o_ref, *, combiner,
+            block_rows):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    dtype = o_ref.dtype
-    ident = combiner.ident_for(dtype)
+    dtype = np.dtype(o_ref.dtype)
+    ident = dtype.type(combiner.ident_for(dtype))  # no dtype promotion
+    reduce = _REDUCE[combiner.name]
 
     @pl.when(j == 0)
     def _init():
@@ -65,37 +59,18 @@ def _kernel(cs_ref, nc_ref, seg_ref, vals_ref, o_ref, *, combiner, block_rows):
 
     @pl.when(j < nc_ref[i])
     def _compute():
-        row0 = i * block_rows
-        seg = seg_ref[:, 0]  # (BE,) global segment id per edge
-        vals = vals_ref[...]  # (BE, D)
-        rel = seg - row0
-        in_block = (rel >= 0) & (rel < block_rows)
-        vals = jnp.where(in_block[:, None], vals, ident)
-
-        scanned = _segmented_scan(vals, seg, combiner.fn, ident)
-
-        # Segment ends: last element of each equal-seg run, plus a virtual
-        # end at the chunk boundary (partials combine across chunks).
-        nxt = jnp.concatenate([seg[1:], jnp.full((1,), -2, seg.dtype)], 0)
-        is_end = (seg != nxt) & in_block
-
-        # <=1 end per row per chunk, so a one-hot matmul extracts it exactly.
-        rows = jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], block_rows), 1)
-        onehot = (rel[:, None] == rows) & is_end[:, None]
-        safe = jnp.where(is_end[:, None], scanned, jnp.zeros_like(scanned))
-        if jnp.issubdtype(dtype, jnp.integer):
-            acc_t = jnp.int32
-        else:
-            acc_t = jnp.float32
-        cand = jax.lax.dot_general(
-            onehot.astype(acc_t).T,
-            safe.astype(acc_t),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_t,
-        ).astype(dtype)
-        has_end = onehot.any(axis=0)
-        cand = jnp.where(has_end[:, None], cand, ident)
-        o_ref[...] = combiner.fn(o_ref[...], cand)
+        rel = seg_ref[...] - i * block_rows  # (R, L) row within the block
+        rows = jax.lax.broadcasted_iota(
+            jnp.int32, (block_rows, rel.shape[1]), 0)
+        for d in range(o_ref.shape[0]):
+            part = None
+            for r in range(rel.shape[0]):
+                mine = rows == rel[r:r + 1, :]  # (BR, L): edge -> out row
+                picked = jnp.where(mine, vals_ref[d, r:r + 1, :], ident)
+                p = reduce(picked, axis=1, keepdims=True)  # (BR, 1)
+                part = p if part is None else combiner.fn(part, p)
+            row = col_to_row(part, ident, reduce)  # (1, BR)
+            o_ref[d:d + 1, :] = combiner.fn(o_ref[d:d + 1, :], row)
 
 
 def segment_combine_pallas(
@@ -120,40 +95,52 @@ def segment_combine_pallas(
       chunk_start: (NB,) int32 first covering chunk per row block.
       num_chunks: (NB,) int32 number of covering chunks per row block.
       num_segments: output rows (padded to a multiple of block_rows).
+      combiner: ``sum``, ``min`` or ``max`` (name or Combiner).
+      block_edges: chunk length (at most 128, or a multiple of 128).
       max_chunks: static bound on per-block chunk count (grid dim).
     Returns:
       (num_segments, D) combined values (identity for empty segments).
     """
     combiner = cb.get(combiner)
+    if combiner.name not in _REDUCE:
+        raise ValueError(
+            f"segment_combine kernel has no reduction for combiner "
+            f"{combiner.name!r} (one of {tuple(_REDUCE)})")
     E, D = vals.shape
     assert E % block_edges == 0, (E, block_edges)
     assert num_segments % block_rows == 0, (num_segments, block_rows)
     nb = num_segments // block_rows
     ec = E // block_edges
+    r, l = chunk_tile(block_edges)
     grid = (nb, max(int(max_chunks), 1))
 
-    def seg_map(i, j, cs_ref, nc_ref):
+    def chunk(i, j, cs_ref, nc_ref):
         c = cs_ref[i] + jnp.minimum(j, jnp.maximum(nc_ref[i] - 1, 0))
-        return (jnp.clip(c, 0, ec - 1), 0)
+        return jnp.clip(c, 0, ec - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_edges, 1), seg_map),
-            pl.BlockSpec((block_edges, D), seg_map),
+            pl.BlockSpec((pl.squeezed, r, l),
+                         lambda *a: (chunk(*a), 0, 0)),
+            pl.BlockSpec((D, pl.squeezed, r, l),
+                         lambda *a: (0, chunk(*a), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows, D), lambda i, j, cs, nc: (i, 0)),
+        out_specs=pl.BlockSpec((pl.squeezed, D, block_rows),
+                               lambda i, j, cs, nc: (i, 0, 0)),
     )
-    kernel = functools.partial(_kernel, combiner=combiner, block_rows=block_rows)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, combiner=combiner,
+                               block_rows=block_rows)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_segments, D), vals.dtype),
+        out_shape=jax.ShapeDtypeStruct((nb, D, block_rows), vals.dtype),
         interpret=interpret,
     )(
         jnp.asarray(chunk_start, jnp.int32),
         jnp.asarray(num_chunks, jnp.int32),
-        jnp.asarray(seg_ids, jnp.int32)[:, None],
-        vals,
+        jnp.asarray(seg_ids, jnp.int32).reshape(ec, r, l),
+        vals.T.reshape(D, ec, r, l),
     )
+    return out.transpose(0, 2, 1).reshape(num_segments, D)

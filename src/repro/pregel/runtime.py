@@ -175,17 +175,6 @@ class RunResult:
         return {k: int(v[q]) for k, v in self.query_msgs_by_channel.items()}
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map vs experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental import shard_map as _sm
-
-    return _sm.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def _scalar(x):
     """() view of a flag that may be per-worker replicated ((W,) or ())."""
     return jnp.asarray(x).reshape(-1)[0] if jnp.ndim(x) else jnp.asarray(x)
@@ -506,11 +495,12 @@ def compile_supersteps(
                 return new_state, halt, ovf, nb, nm, novf
 
             extra = (P(),) if num_queries is not None else ()
-            return _shard_map(
+            return jax.shard_map(
                 device_step,
                 mesh=mesh,
                 in_specs=(P(axis), P(axis), P()) + extra,
                 out_specs=(P(axis), P(), P(), P(), P(), P()),
+                check_vma=False,
             )
         raise ValueError(backend)
 

@@ -319,6 +319,16 @@ def as_queue(requests) -> QueryQueue:
     return QueryQueue.from_queries(requests)
 
 
+@jax.jit
+def _write_lane(state, qstate, lane):
+    """Admission: overwrite query lane ``lane`` of every state leaf.
+    Jitted so the write keeps the state's sharding — on a mesh with
+    Explicit axes (``jax.make_mesh``'s default) an eager scatter into a
+    sharded leaf is refused outside a mesh context."""
+    return jax.tree_util.tree_map(
+        lambda leaf, new: leaf.at[:, lane].set(new), state, qstate)
+
+
 def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
                chunk_size: int, max_steps: int, check_overflow: bool,
                faults: Optional[Sequence] = None,
@@ -369,10 +379,8 @@ def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
             entry = queue.pop_ready(clock)
             if entry is None:
                 break
-            qstate = prog.query_init(pg, entry.query)
-            state = jax.tree_util.tree_map(
-                lambda leaf, new, _l=lane: leaf.at[:, _l].set(new),
-                state, qstate)
+            state = _write_lane(state, prog.query_init(pg, entry.query),
+                                np.int32(lane))
             age[lane] = 0
             halted[lane] = False
             overflow[lane] = False

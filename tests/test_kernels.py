@@ -113,3 +113,73 @@ def test_min_by_first_combiner_property(seed, n):
         else:
             i = np.flatnonzero(sel)[np.argmin(keys[sel])]
             np.testing.assert_allclose(got[s], vals[i], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# lane-dense kernel bodies (the layouts the TPU lowering uses) vs kref,
+# exact: lattice combiners, integer sums, and f32 sums of small integers
+# (exact in any summation order)
+# ---------------------------------------------------------------------------
+
+SEG_CASES = {
+    # id: (combiner, dtype, e, n, d, block_rows, block_edges)
+    "int32-min": ("min", np.int32, 3000, 700, 1, 128, 1024),
+    "int32-max": ("max", np.int32, 3000, 700, 1, 128, 1024),
+    "f32-sum": ("sum", np.float32, 3000, 700, 1, 128, 512),
+    "f32-min-d8": ("min", np.float32, 2500, 300, 8, 128, 256),
+    "int32-sum-d3": ("sum", np.int32, 1000, 64, 3, 64, 128),
+    "empty-segments": ("min", np.int32, 200, 5000, 1, 128, 1024),
+    "one-row-block": ("max", np.int32, 100, 8, 2, 8, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEG_CASES))
+def test_segment_combine_kernel_exact(case):
+    combiner, dtype, e, n, d, br, be = SEG_CASES[case]
+    rng = np.random.default_rng(len(case))
+    seg = np.sort(rng.integers(0, n, e)).astype(np.int32)
+    vals = rng.integers(-50, 50, (e, d)).astype(dtype)
+    want = ref.segment_combine_ref(jnp.array(vals), jnp.array(seg), n,
+                                   combiner)
+    got = ops.segment_combine(jnp.array(vals), jnp.array(seg), n, combiner,
+                              use_kernel=True, assume_sorted=True,
+                              block_rows=br, block_edges=be)
+    if case == "empty-segments":
+        assert (np.bincount(seg, minlength=n) == 0).sum() > n // 2
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_segment_combine_kernel_rejects_unreducible_combiner():
+    vals = jnp.ones((128, 1), jnp.float32)
+    seg = jnp.zeros((128,), jnp.int32)
+    with pytest.raises(ValueError, match="no reduction"):
+        ops.segment_combine(vals, seg, 4, "prod", use_kernel=True,
+                            assume_sorted=True)
+
+
+BUCKET_CASES = {
+    # id: (m, num_buckets, block_msgs, lanes Q or None)
+    "single-bucket": (700, 1, 1024, None),
+    "m-not-multiple": (3000, 8, 1024, None),
+    "narrow-chunks": (1000, 4, 128, None),
+    "lanes-q8": (2500, 8, 1024, 8),
+    "lanes-single-bucket": (300, 1, 128, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BUCKET_CASES))
+def test_bucket_ranks_kernel_exact(case):
+    m, b, bm, q = BUCKET_CASES[case]
+    rng = np.random.default_rng(m + b)
+    keys = jnp.asarray(rng.integers(0, b + 1, m).astype(np.int32))
+    if q is None:
+        got = ops.bucket_ranks(keys, b, use_kernel=True, block_msgs=bm)
+        want = ref.bucket_ranks_ref(keys, b)
+    else:
+        lanes = jnp.asarray(rng.random((m, q)) < 0.5) \
+            & (keys < b)[:, None]
+        got = ops.bucket_ranks_lanes(keys, lanes, b, use_kernel=True,
+                                     block_msgs=bm)
+        want = ref.bucket_ranks_lanes_ref(keys, lanes, b)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
