@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent, while serving: 1 - (union of device-op intervals) / window."""
+
+
+def read(run):
+    share = run.trace.idle_share() if run.window.answers else None
+    return None if share is None else 100.0 * share
