@@ -1,0 +1,10 @@
+"""Share of the device's busy time over the jobs' window, in percent,
+that no owner inside the loop claims: operations no scope names, or named
+only by the bare ``superstep`` loop (``scopes.unscoped``). What the scope
+metrics cannot see."""
+
+import scopes
+
+
+def read(run):
+    return scopes.unscoped_share(run) if run.window.jobs else None

@@ -164,7 +164,6 @@ def bench_quarantine(scale: int, q: int = 12, lanes: int = 4,
         "statuses": {str(r.qid): r.status for r in res.records},
         "served": int(res.num_queries),
         "wall_s": wall,
-        "straggler_dispatches": list(res.straggler_dispatches),
         "dispatch_median_s": float(res.dispatch_median_s),
         "quarantine_isolated": bool(
             healthy_identical

@@ -19,8 +19,10 @@ from repro.core import message as msg
 from repro.core import request_respond as rr
 from repro.core import routing
 from repro.core.channel import TRAFFIC_DTYPE, ChannelContext
+from repro.pregel import tracing
 
 
+@tracing.channel_call
 def direct_request_respond(
     ctx: ChannelContext,
     dst: jax.Array,
@@ -67,30 +69,34 @@ def direct_request_respond(
         name=name + "/request", wire_width=wire_width,
     )
     # phase 2: respond to each request individually.
-    tgt_vals = jnp.concatenate([rv, jnp.zeros((1, d), rv.dtype)], 0)[
-        jnp.clip(deliv.dst_local, 0, n_loc)
-    ]  # (W*C, D) value of the requested vertex
-    back_payload = {"v": tgt_vals}
-    if tagged:
-        back_payload["tag"] = deliv.payload["tag"]
-    back = msg.direct_send(
-        ctx,
-        deliv.payload["requester"],
-        deliv.mask,
-        back_payload,
-        capacity=r,
-        name=name + "/respond",
-        wire_width=wire_width,
-    )
-    slot = back.payload["tag"] if tagged else back.dst_local
-    out = jnp.zeros((r + 1, d), rv.dtype)
-    out = out.at[jnp.where(back.mask, slot, r)].set(
-        jnp.where(back.mask[:, None], back.payload["v"], 0), mode="drop"
-    )[:r]
+    with tracing.channel(ctx.full_name(name + "/respond")):
+        tgt_vals = jnp.concatenate([rv, jnp.zeros((1, d), rv.dtype)], 0)[
+            jnp.clip(deliv.dst_local, 0, n_loc)
+        ]  # (W*C, D) value of the requested vertex
+        back_payload = {"v": tgt_vals}
+        if tagged:
+            back_payload["tag"] = deliv.payload["tag"]
+        back = msg.direct_send(
+            ctx,
+            deliv.payload["requester"],
+            deliv.mask,
+            back_payload,
+            capacity=r,
+            name=name + "/respond",
+            wire_width=wire_width,
+        )
+        slot = back.payload["tag"] if tagged else back.dst_local
+        with tracing.scope(tracing.EXCHANGE):
+            out = jnp.zeros((r + 1, d), rv.dtype)
+            out = out.at[jnp.where(back.mask, slot, r)].set(
+                jnp.where(back.mask[:, None], back.payload["v"], 0),
+                mode="drop"
+            )[:r]
     overflow = deliv.overflow | back.overflow
     return (out[:, 0] if squeeze else out), overflow
 
 
+@tracing.channel_call
 def cm_propagate(
     ctx: ChannelContext,
     raw_edges,
@@ -155,6 +161,7 @@ def jump_component():
     return compose.Component(fn)
 
 
+@tracing.channel_call
 def pj_converge(ctx: ChannelContext, parents, mask, *, use_reqresp=True,
                 max_iters: int = 64, name: str = "pj_loop",
                 wire_width: int = None):

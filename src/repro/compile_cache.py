@@ -9,6 +9,13 @@ this module, or the library, changes nothing.
   - Otherwise the cache goes to ``<checkout>/.jax_cache``: one fixed
     path (listed in ``.gitignore``), so a later run from the same
     checkout finds what an earlier one compiled.
+
+Either way an entry's key covers the program's scope names: an
+executable read back carries the names of the code that asked for it,
+which ``repro.pregel.tracing`` reads, and never those of another checkout
+that compiled the same instructions. Source locations are left out of the
+programs (and so of the key), so moving a line or starting from another
+script still finds the entry.
 """
 from __future__ import annotations
 
@@ -38,4 +45,6 @@ def enable() -> str:
     path = default_dir()
     if path is not None:
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return jax.config.jax_compilation_cache_dir

@@ -15,8 +15,10 @@ import jax.numpy as jnp
 
 from repro.core import combiners as cb
 from repro.core.channel import ChannelContext
+from repro.pregel import tracing
 
 
+@tracing.channel_call
 def aggregate(
     ctx: ChannelContext,
     values: jax.Array,

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.configs import knobs
 from repro.core.channel import TRAFFIC_DTYPE, ChannelContext, key_under
+from repro.pregel import tracing
 
 #: the density-switch threshold knob (explicit > dense_threshold_scope >
 #: REPRO_DENSE_THRESHOLD > 0.1): the frontier fraction at or above which
@@ -169,7 +170,9 @@ class Stacked:
 
     def call(self, ctx: ChannelContext, key: str, *args, **kw):
         comp = self.components[key]
-        return comp.fn(ctx, f"{self.name}/{key}", *args, **kw)
+        name = f"{self.name}/{key}"
+        with tracing.channel(ctx.full_name(name)):
+            return comp.fn(ctx, name, *args, **kw)
 
     __call__ = call
 
@@ -282,22 +285,29 @@ def fused_exchange(ctx: ChannelContext, parts: Sequence[PlannedExchange]) -> lis
     recv_leaves: List[List[Optional[jax.Array]]] = [
         [None] * len(leaves) for leaves, _ in flat_parts
     ]
-    for items in groups.values():
-        w = items[0][2].shape[0]
-        cols = [leaf.reshape(w, -1) for _, _, leaf in items]
-        widths = [col.shape[1] for col in cols]
-        merged = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
-        back = jax.lax.all_to_all(merged, ctx.axis, 0, 0, tiled=True)
-        off = 0
-        for (pi, li, leaf), width in zip(items, widths):
-            recv_leaves[pi][li] = back[:, off : off + width].reshape(leaf.shape)
-            off += width
+    # one part owns its collective; a merged round is shared by its parts
+    owner = (tracing.channel(ctx.full_name(parts[0].name))
+             if len(parts) == 1 else contextlib.nullcontext())
+    with owner, tracing.scope(tracing.EXCHANGE):
+        for items in groups.values():
+            w = items[0][2].shape[0]
+            cols = [leaf.reshape(w, -1) for _, _, leaf in items]
+            widths = [col.shape[1] for col in cols]
+            merged = (cols[0] if len(cols) == 1
+                      else jnp.concatenate(cols, axis=1))
+            back = jax.lax.all_to_all(merged, ctx.axis, 0, 0, tiled=True)
+            off = 0
+            for (pi, li, leaf), width in zip(items, widths):
+                recv_leaves[pi][li] = back[:, off: off + width].reshape(
+                    leaf.shape)
+                off += width
 
     results = []
     for pi, part in enumerate(parts):
         ctx.add_traffic(part.name, part.nbytes, part.nmsgs)
         recv = jax.tree_util.tree_unflatten(flat_parts[pi][1], recv_leaves[pi])
-        results.append(part.finish(recv))
+        with tracing.channel(ctx.full_name(part.name)):
+            results.append(part.finish(recv))
     return results
 
 

@@ -34,6 +34,7 @@ from repro.core import combiners as cb
 from repro.core import routing
 from repro.core.channel import ChannelContext, payload_width
 from repro.kernels import ops as kops
+from repro.pregel import tracing
 
 
 @dataclasses.dataclass
@@ -46,6 +47,7 @@ class Delivery:
     overflow: jax.Array    # () bool
 
 
+@tracing.scope(tracing.EXCHANGE)
 def _delivery(ctx: ChannelContext, routed: routing.Routed, capacity: int):
     """Flatten a Routed into per-message local-index delivery form."""
     w, c = ctx.num_workers, capacity
@@ -64,6 +66,7 @@ def _delivery(ctx: ChannelContext, routed: routing.Routed, capacity: int):
     )
 
 
+@tracing.channel_call
 def direct_send(
     ctx: ChannelContext,
     dst: jax.Array,
@@ -186,58 +189,66 @@ def _combined_send_union(ctx, dst, valid, v, combiner, capacity, use_kernel):
 
         # ---- union dedup over the id space (one histogram, all lanes) ----
         u_cap = min(q * m, n_total)
-        u_dst, pos = routing.union_dedup(dst2, valid_eff, n_total, u_cap)
-        u_valid = u_dst != routing.BIG
-        # per-lane combine into the SHARED compact space; lane membership
-        # marks which unique ids each lane actually sends
-        seg_l = jnp.where(
-            valid_eff, pos[jnp.clip(dst2, 0, n_total - 1)], u_cap)  # (Q, M)
+        with tracing.scope(tracing.ROUTE):
+            u_dst, pos = routing.union_dedup(dst2, valid_eff, n_total, u_cap)
+            u_valid = u_dst != routing.BIG
+            # the lanes' positions in the SHARED compact space
+            seg_l = jnp.where(
+                valid_eff, pos[jnp.clip(dst2, 0, n_total - 1)], u_cap)
+        # per-lane sender-side combine into the shared space
         u_vals = jax.vmap(
             lambda vv, ss: combiner.segment_reduce(vv, ss, u_cap)
         )(v2, seg_l)  # (Q, u_cap, D)
-        lane_has = (
-            jnp.zeros((q, u_cap + 1), jnp.int32)
-            .at[jnp.arange(q)[:, None], seg_l]
-            .add(1)[:, :u_cap]
-            > 0
-        )  # (Q, u_cap)
+        with tracing.scope(tracing.ROUTE):
+            # lane membership marks which unique ids each lane sends
+            lane_has = (
+                jnp.zeros((q, u_cap + 1), jnp.int32)
+                .at[jnp.arange(q)[:, None], seg_l]
+                .add(1)[:, :u_cap]
+                > 0
+            )  # (Q, u_cap)
 
-        # ---- ONE bucket-route pass over the union unique list ----
-        owner_u = jnp.clip(u_dst // n_loc, 0, W - 1)
-        key_u = jnp.where(u_valid, owner_u, W).astype(jnp.int32)
-        lanes = lane_has.T  # (u_cap, Q)
-        rank, count, lane_counts = routing.union_ranks(
-            key_u, lanes, W, impl=impl, use_kernel=use_kernel)
-        fits = rank < c
-        packed = u_valid & fits
-        slot = jnp.where(packed, key_u * c + rank, W * c)
-        ovf_l = jnp.any(lane_has & ~fits[None, :], axis=1)  # (Q,)
-        sent_l = jnp.minimum(lane_counts, c)  # (W, Q)
-        me = jax.lax.axis_index(ax)
-        remote_l = (sent_l.sum(axis=0) - sent_l[me]).astype(
-            routing.TRAFFIC_DTYPE)  # (Q,)
+            # ---- ONE bucket-route pass over the union unique list ----
+            owner_u = jnp.clip(u_dst // n_loc, 0, W - 1)
+            key_u = jnp.where(u_valid, owner_u, W).astype(jnp.int32)
+            lanes = lane_has.T  # (u_cap, Q)
+            rank, count, lane_counts = routing.union_ranks(
+                key_u, lanes, W, impl=impl, use_kernel=use_kernel)
+            fits = rank < c
+            packed = u_valid & fits
+            slot = jnp.where(packed, key_u * c + rank, W * c)
+            ovf_l = jnp.any(lane_has & ~fits[None, :], axis=1)  # (Q,)
+            sent_l = jnp.minimum(lane_counts, c)  # (W, Q)
+            me = jax.lax.axis_index(ax)
+            remote_l = (sent_l.sum(axis=0) - sent_l[me]).astype(
+                routing.TRAFFIC_DTYPE)  # (Q,)
 
         # ---- pack + one all_to_all per leaf: ids, lane mask, lane values
-        def pack(leafT, fill):
+        @tracing.scope(tracing.ROUTE)
+        def pack(leafT, fill):  # (W, c, ...) send buffer
             shape = (W * c + 1,) + leafT.shape[1:]
             buf = jnp.full(shape, fill, leafT.dtype)
-            return buf.at[slot].set(leafT, mode="drop")[: W * c]
+            return buf.at[slot].set(leafT, mode="drop")[: W * c].reshape(
+                (W, c) + leafT.shape[1:])
 
-        recv_ids = jax.lax.all_to_all(
-            pack(u_dst, routing.BIG).reshape(W, c), ax, 0, 0, tiled=True)
-        recv_has = jax.lax.all_to_all(
-            pack(lanes, False).reshape(W, c, q), ax, 0, 0, tiled=True)
+        send_ids = pack(u_dst, routing.BIG)
+        with tracing.scope(tracing.EXCHANGE):
+            recv_ids = jax.lax.all_to_all(send_ids, ax, 0, 0, tiled=True)
+        send_has = pack(lanes, False)
+        with tracing.scope(tracing.EXCHANGE):
+            recv_has = jax.lax.all_to_all(send_has, ax, 0, 0, tiled=True)
         vmat = jnp.where(
             lanes[:, :, None], jnp.moveaxis(u_vals, 0, 1), ident)
-        recv_v = jax.lax.all_to_all(
-            pack(vmat, ident).reshape(W, c, q, d), ax, 0, 0, tiled=True)
+        send_v = pack(vmat, ident)
+        with tracing.scope(tracing.EXCHANGE):
+            recv_v = jax.lax.all_to_all(send_v, ax, 0, 0, tiled=True)
+            flat_ids = recv_ids.reshape(-1)
+            flat_has = recv_has.reshape(W * c, q)
+            dst_local = jnp.where(
+                flat_ids != routing.BIG, flat_ids - me * n_loc, n_loc
+            ).astype(jnp.int32)
 
         # ---- receiver-side per-lane combine: one segment pass over Q·D
-        flat_ids = recv_ids.reshape(-1)
-        flat_has = recv_has.reshape(W * c, q)
-        dst_local = jnp.where(
-            flat_ids != routing.BIG, flat_ids - me * n_loc, n_loc
-        ).astype(jnp.int32)
         flat_v = jnp.where(
             flat_has[:, :, None], recv_v.reshape(W * c, q, d), ident)
         out = kops.segment_combine(
@@ -254,6 +265,7 @@ def _combined_send_union(ctx, dst, valid, v, combiner, capacity, use_kernel):
               jnp.asarray(dst, jnp.int32), valid, v)
 
 
+@tracing.channel_call
 def combined_send(
     ctx: ChannelContext,
     dst: jax.Array,
@@ -297,6 +309,7 @@ def combined_send(
     return (out[:, 0] if squeeze else out), got, overflow
 
 
+@tracing.channel_call
 def monolithic_send(
     ctx: ChannelContext,
     dst: jax.Array,

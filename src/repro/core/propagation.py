@@ -24,8 +24,10 @@ from repro.core import combiners as cb
 from repro.core.channel import TRAFFIC_DTYPE, ChannelContext
 from repro.graph.pgraph import PropPlan
 from repro.kernels import ops as kops
+from repro.pregel import tracing
 
 
+@tracing.channel_call
 def propagate(
     ctx: ChannelContext,
     plan: PropPlan,
@@ -110,8 +112,9 @@ def propagate(
         mine = prev_hub
         if hub_cap:
             mine = jnp.where(exported[:, None], base[hub_safe], ident)
-            hubs = jax.lax.all_gather(mine, ctx.axis)  # (W, hub_cap, D)
-            base = jnp.concatenate([base, hubs.reshape(-1, d)], axis=0)
+            with tracing.scope(tracing.EXCHANGE):
+                hubs = jax.lax.all_gather(mine, ctx.axis)  # (W, hub_cap, D)
+                base = jnp.concatenate([base, hubs.reshape(-1, d)], axis=0)
             changed_h = jnp.sum(
                 jnp.any(mine != prev_hub, axis=-1) & exported
             ).astype(TRAFFIC_DTYPE)
@@ -132,11 +135,12 @@ def propagate(
         )
         changed_u = jnp.any(u_vals != prev_u, axis=-1) & (u_owner != w)
         remote_changed = jnp.sum(changed_u & (u_owner != me)).astype(TRAFFIC_DTYPE)
-        buf = jnp.full((w * c + 1, d), ident, dtype)
-        buf = buf.at[plan.cut.pack_slot].set(u_vals, mode="drop")
-        recv = jax.lax.all_to_all(
-            buf[: w * c].reshape(w, c, d), ctx.axis, 0, 0, tiled=True
-        )
+        with tracing.scope(tracing.ROUTE):
+            buf = jnp.full((w * c + 1, d), ident, dtype)
+            buf = buf.at[plan.cut.pack_slot].set(u_vals, mode="drop")
+            send = buf[: w * c].reshape(w, c, d)
+        with tracing.scope(tracing.EXCHANGE):
+            recv = jax.lax.all_to_all(send, ctx.axis, 0, 0, tiled=True)
         inc = kops.segment_combine(
             recv.reshape(w * c, d), plan.cut.recv_local.reshape(-1), n_loc,
             combiner, use_kernel=False,

@@ -56,6 +56,7 @@ from jax.custom_batching import custom_vmap
 from repro.configs import knobs
 from repro.core.channel import TRAFFIC_DTYPE
 from repro.kernels import ops as kops
+from repro.pregel import tracing
 from repro.pregel.errors import PlanRangeError
 
 BIG = jnp.iinfo(jnp.int32).max
@@ -171,6 +172,7 @@ def _slots_sort(key, w: int):
     return rank, bounds[1:] - bounds[:-1]
 
 
+@tracing.scope(tracing.ROUTE)
 def route(
     ctx,
     dst,
@@ -219,13 +221,15 @@ def route(
         return buf.at[slot].set(leaf, mode="drop")[: W * c]
 
     send_ids = pack(ids, BIG).reshape(W, c)
-    recv_ids = jax.lax.all_to_all(send_ids, ax, 0, 0, tiled=True)
-    recv_mask = recv_ids != BIG
+    with tracing.scope(tracing.EXCHANGE):
+        recv_ids = jax.lax.all_to_all(send_ids, ax, 0, 0, tiled=True)
+        recv_mask = recv_ids != BIG
 
     if exchange_payload:
         def xch(leaf):
             packed = pack(leaf, 0).reshape((W, c) + leaf.shape[1:])
-            return jax.lax.all_to_all(packed, ax, 0, 0, tiled=True)
+            with tracing.scope(tracing.EXCHANGE):
+                return jax.lax.all_to_all(packed, ax, 0, 0, tiled=True)
         recv_payload = jax.tree_util.tree_map(xch, payload)
     else:
         recv_payload = None
@@ -263,6 +267,7 @@ def route(
 # --------------------------------------------------------------------------
 
 
+@tracing.scope(tracing.ROUTE)
 def union_dedup(dst_l, valid_l, n_total: int, u_cap: int):
     """:func:`dedup_dense` across Q lanes at once: the compact ascending
     unique list over the UNION of every lane's valid destinations.
@@ -306,6 +311,7 @@ def union_ranks(key, lanes, w: int, impl: Optional[str] = None,
     return rank, count, lane_counts
 
 
+@tracing.scope(tracing.ROUTE)
 def route_union(
     ctx,
     dst,
@@ -399,13 +405,15 @@ def route_union(
             return buf.at[slot].set(leafT, mode="drop")[: W * c]
 
         send_ids = pack(ids, BIG).reshape(W, c)
-        recv_ids = jax.lax.all_to_all(send_ids, ax, 0, 0, tiled=True)
+        with tracing.scope(tracing.EXCHANGE):
+            recv_ids = jax.lax.all_to_all(send_ids, ax, 0, 0, tiled=True)
         # per-lane wire membership rides as one (slots, Q) lane matrix
         send_mask = pack(lanes, False).reshape(W, c, q)
-        recv_mask = jax.lax.all_to_all(send_mask, ax, 0, 0, tiled=True)
-        out_mask = jnp.moveaxis(recv_mask, 2, 0)  # (Q, W, c)
-        # a lane's ids view pads slots it did not occupy (= serial view)
-        out_ids = jnp.where(out_mask, recv_ids[None], BIG)
+        with tracing.scope(tracing.EXCHANGE):
+            recv_mask = jax.lax.all_to_all(send_mask, ax, 0, 0, tiled=True)
+            out_mask = jnp.moveaxis(recv_mask, 2, 0)  # (Q, W, c)
+            # a lane's ids view pads slots it did not occupy (= serial view)
+            out_ids = jnp.where(out_mask, recv_ids[None], BIG)
 
         out = [out_ids, out_mask, slot_l, sent_l, overflow_l]
         if exchange_payload:
@@ -414,8 +422,9 @@ def route_union(
                 sel = lanes.reshape(lanes.shape + (1,) * (leafT.ndim - 2))
                 leafT = jnp.where(sel, leafT, 0)  # serial pack fill
                 buf = pack(leafT, 0).reshape((W, c, q) + leafT.shape[2:])
-                recv = jax.lax.all_to_all(buf, ax, 0, 0, tiled=True)
-                out.append(jnp.moveaxis(recv, 2, 0))  # (Q, W, c, ...)
+                with tracing.scope(tracing.EXCHANGE):
+                    recv = jax.lax.all_to_all(buf, ax, 0, 0, tiled=True)
+                    out.append(jnp.moveaxis(recv, 2, 0))  # (Q, W, c, ...)
         return tuple(out), tuple(True for _ in out)
 
     outs = ex(ctx.query_index, lane_live(ctx),
@@ -427,6 +436,7 @@ def route_union(
                   sent_count=sent_count, overflow=overflow)
 
 
+@tracing.scope(tracing.EXCHANGE)
 def reply(ctx, routed: Routed, resp):
     """Send per-slot responses back (positionally — no ids on the wire)
     and deliver them in the original message order.
@@ -456,6 +466,7 @@ def remote_count(ctx, sent_count):
     return (sent_count.sum() - sent_count[me]).astype(TRAFFIC_DTYPE)
 
 
+@tracing.scope(tracing.ROUTE)
 def dedup_dense(dst, valid, n_total: int, m_cap: Optional[int] = None):
     """Sort-free per-worker dedup: the compact ascending list of unique
     valid destinations, via a dense occupancy histogram + prefix-sum

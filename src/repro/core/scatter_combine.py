@@ -25,8 +25,10 @@ from repro.core import compose
 from repro.core.channel import TRAFFIC_DTYPE, ChannelContext
 from repro.graph.pgraph import ScatterPlan
 from repro.kernels import ops as kops
+from repro.pregel import tracing
 
 
+@tracing.channel_call
 def plan_broadcast_combine(
     ctx: ChannelContext,
     plan: ScatterPlan,
@@ -67,8 +69,9 @@ def plan_broadcast_combine(
         exported = plan.hub_local < ctx.n_loc  # (hub_cap,) real slots
         safe = jnp.minimum(plan.hub_local, ctx.n_loc - 1)
         mine = jnp.where(exported[:, None], vals[safe], ident)
-        hubs = jax.lax.all_gather(mine, ctx.axis)  # (W, hub_cap, D)
-        vals_ext = jnp.concatenate([vals, hubs.reshape(-1, d)], axis=0)
+        with tracing.scope(tracing.EXCHANGE):
+            hubs = jax.lax.all_gather(mine, ctx.axis)  # (W, hub_cap, D)
+            vals_ext = jnp.concatenate([vals, hubs.reshape(-1, d)], axis=0)
         mirror_msgs = (jnp.sum(exported) * (w - 1)).astype(TRAFFIC_DTYPE)
     else:
         vals_ext = vals
@@ -93,9 +96,10 @@ def plan_broadcast_combine(
     )
 
     # 3. positional pack (payload only — the routing is static)
-    buf = jnp.full((w * c + 1, d), ident, vals.dtype)
-    buf = buf.at[plan.pack_slot].set(u_vals, mode="drop")
-    send = buf[: w * c].reshape(w, c, d)
+    with tracing.scope(tracing.ROUTE):
+        buf = jnp.full((w * c + 1, d), ident, vals.dtype)
+        buf = buf.at[plan.pack_slot].set(u_vals, mode="drop")
+        send = buf[: w * c].reshape(w, c, d)
 
     # 4. (deferred) receive-side combine into dense per-vertex values
     def finish(recv):

@@ -33,6 +33,7 @@ from repro.core import combiners as cb
 from repro.kernels import bucket_route as kbucket
 from repro.kernels import ref as kref
 from repro.kernels import segment_combine as kseg
+from repro.pregel import tracing
 
 #: the kernel-vs-reference knob (explicit > use_kernel_scope >
 #: REPRO_USE_KERNEL > backend default) — see repro.configs.knobs
@@ -123,6 +124,7 @@ def plan_chunks(seg_ids_np, num_segments, block_rows, block_edges):
 # ---------------------------------------------------------------------------
 
 
+@tracing.scope(tracing.COMBINE)
 def segment_combine(
     vals,
     seg_ids,
@@ -139,7 +141,8 @@ def segment_combine(
     """Segment reduction: out[s] = combine(vals[e] for seg_ids[e] == s).
 
     Entries with seg_ids >= num_segments are dropped. The kernel path
-    requires sorted seg_ids (assume_sorted or it sorts internally).
+    requires sorted seg_ids (assume_sorted or it sorts internally). Both
+    paths run under the ``combine`` device scope.
     """
     combiner = cb.get(combiner)
     if not resolve_use_kernel(use_kernel):
@@ -199,6 +202,7 @@ def segment_combine(
     return out[:, 0] if squeeze else out
 
 
+@tracing.scope(tracing.COMBINE)
 def gather_segment_combine(
     src_vals, edge_src, seg_ids, num_segments, combiner, **kw
 ):

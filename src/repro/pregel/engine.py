@@ -48,6 +48,7 @@ from repro.pregel import checkpoint as ckpt_io
 from repro.pregel import errors
 from repro.pregel import runtime
 from repro.pregel import serve as serving
+from repro.pregel import tracing
 from repro.pregel.program import VertexProgram
 
 
@@ -355,7 +356,8 @@ class Engine:
         """
         ms = prog.max_steps if max_steps is None else max_steps
         co = prog.check_overflow if check_overflow is None else check_overflow
-        state0 = prog.init(pg)
+        with tracing.span("job/init"):
+            state0 = prog.init(pg)
 
         resume_carry = None
         if resume is not None:
@@ -380,8 +382,9 @@ class Engine:
         recovery: List[Dict[str, Any]] = []
         attempt = 0
         while True:
-            exe, hit, plan = self._compile_cached(
-                prog, pg, state0, ms, co, cap_scales=scales)
+            with tracing.span("job/lookup"):
+                exe, hit, plan = self._compile_cached(
+                    prog, pg, state0, ms, co, cap_scales=scales)
             try:
                 raw = exe.execute(pg, state0,
                                   checkpoint_every=checkpoint_every,
@@ -404,7 +407,8 @@ class Engine:
         if recovery:
             res.recovery = recovery
             self._remember_scales(prog, pg, 0, scales)
-        res.output = prog.extract(pg, res.state)
+        with tracing.span("job/extract"):
+            res.output = prog.extract(pg, res.state)
         self._check_converged(prog, res)
         return res
 
@@ -445,12 +449,14 @@ class Engine:
         queries = list(queries)
         q = len(queries)
         cap = bucket_queries(q)
-        per_query = [prog.query_init(pg, query) for query in queries]
-        # pad lanes reuse the first real state by reference — jnp.stack
-        # copies anyway, so re-running query_init for them buys nothing
-        per_query += [per_query[0]] * (cap - q)
-        state0 = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves, axis=1), *per_query)
+        with tracing.span("job/init"):
+            per_query = [prog.query_init(pg, query) for query in queries]
+            # pad lanes reuse the first real state by reference —
+            # jnp.stack copies anyway, so re-running query_init for them
+            # buys nothing
+            per_query += [per_query[0]] * (cap - q)
+            state0 = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves, axis=1), *per_query)
 
         ms = prog.max_steps if max_steps is None else max_steps
         co = prog.check_overflow if check_overflow is None else check_overflow
@@ -458,9 +464,10 @@ class Engine:
         recovery: List[Dict[str, Any]] = []
         attempt = 0
         while True:
-            exe, hit, plan = self._compile_cached(
-                prog, pg, state0, ms, co, key_extra=("batch", cap),
-                num_queries=cap, cap_scales=scales)
+            with tracing.span("job/lookup"):
+                exe, hit, plan = self._compile_cached(
+                    prog, pg, state0, ms, co, key_extra=("batch", cap),
+                    num_queries=cap, cap_scales=scales)
             try:
                 raw = exe.execute(pg, state0, num_real_queries=q)
                 break
@@ -483,11 +490,12 @@ class Engine:
         if recovery:
             res.recovery = recovery
             self._remember_scales(prog, pg, cap, scales)
-        res.outputs = [
-            prog.extract(pg, jax.tree_util.tree_map(
-                lambda leaf, _qi=qi: leaf[:, _qi], res.state))
-            for qi in range(q)
-        ]
+        with tracing.span("job/extract"):
+            res.outputs = [
+                prog.extract(pg, jax.tree_util.tree_map(
+                    lambda leaf, _qi=qi: leaf[:, _qi], res.state))
+                for qi in range(q)
+            ]
         res.output = res.outputs
         self._check_converged(prog, res)
         return res

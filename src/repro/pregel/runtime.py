@@ -71,6 +71,7 @@ from repro.core.channel import ChannelContext, ChannelRegistry, key_under
 from repro.graph.pgraph import PartitionedGraph
 from repro.kernels import ops as kops
 from repro.pregel import errors
+from repro.pregel import tracing
 
 AXIS = "workers"
 
@@ -416,15 +417,17 @@ def compile_supersteps(
                     query_index=qinfo[0], query_live=qinfo[1],
                     num_queries=num_queries,
                     route_cap=graph.route_cap)
-            out = step_fn(ctx, g_shard, state_shard, step_idx)
+            with tracing.scope(tracing.COMPUTE):
+                out = step_fn(ctx, g_shard, state_shard, step_idx)
             if len(out) == 3:
                 new_state, halt, overflow = out
             else:
                 new_state, halt = out
                 overflow = jnp.asarray(False)
-            halt_all = aggregator.all_halted(ctx, halt)
-            overflow_any = jax.lax.psum(
-                jnp.asarray(overflow, jnp.int32), axis) > 0
+            with tracing.scope(tracing.VOTE):
+                halt_all = aggregator.all_halted(ctx, halt)
+                overflow_any = jax.lax.psum(
+                    jnp.asarray(overflow, jnp.int32), axis) > 0
             traced_names.update(ctx.touched)  # host-side, at trace time
             nbytes, nmsgs = ctx.stats()
             novf = dict(ctx.stats_ovf)
@@ -435,11 +438,12 @@ def compile_supersteps(
                 # global per-step total on device — same totals, either
                 # backend
                 psum = lambda v: jax.lax.psum(v, axis)
-                nbytes = jax.tree_util.tree_map(psum, nbytes)
-                nmsgs = jax.tree_util.tree_map(psum, nmsgs)
-                novf = jax.tree_util.tree_map(
-                    lambda v: jax.lax.psum(
-                        jnp.asarray(v, jnp.int32), axis) > 0, novf)
+                with tracing.scope(tracing.VOTE):
+                    nbytes = jax.tree_util.tree_map(psum, nbytes)
+                    nmsgs = jax.tree_util.tree_map(psum, nmsgs)
+                    novf = jax.tree_util.tree_map(
+                        lambda v: jax.lax.psum(
+                            jnp.asarray(v, jnp.int32), axis) > 0, novf)
             return new_state, halt_all, overflow_any, nbytes, nmsgs, novf
 
         return shard_step
@@ -546,46 +550,52 @@ def compile_supersteps(
         mapped = map_shards(make_shard_step(registry))
         i0 = jnp.asarray(0, jnp.int32)
 
+        def jit(fn):
+            # every instruction of the loop is superstep work
+            return jax.jit(tracing.scope(tracing.SUPERSTEP)(fn))
+
         tc = time.perf_counter()
-        if num_queries is not None:
-            h0 = jnp.zeros((num_queries,), bool)
-            if serve:
-                a0 = jnp.zeros((num_queries,), jnp.int32)
-                fn = (jax.jit(_make_serve_chunk(
-                        mapped, registry, max_steps, check_overflow,
-                        chunk_size, num_queries))
-                      .lower(graph, state0, a0, h0, h0).compile())
+        with tracing.span(tracing.COMPILE):
+            if num_queries is not None:
+                h0 = jnp.zeros((num_queries,), bool)
+                if serve:
+                    a0 = jnp.zeros((num_queries,), jnp.int32)
+                    fn = (jit(_make_serve_chunk(
+                            mapped, registry, max_steps, check_overflow,
+                            chunk_size, num_queries))
+                          .lower(graph, state0, a0, h0, h0).compile())
+                elif mode == "host":
+                    fn = (jit(_make_batched_step(mapped, num_queries))
+                          .lower(graph, state0, i0, h0).compile())
+                elif mode == "fused":
+                    fn = (jit(_make_batched_fused_loop(
+                            mapped, registry, max_steps, check_overflow,
+                            num_queries))
+                          .lower(graph, state0, h0).compile())
+                else:
+                    fn = (jit(_make_batched_chunk(
+                            mapped, registry, max_steps, check_overflow,
+                            chunk_size, num_queries))
+                          .lower(graph, state0, i0, h0, h0).compile())
             elif mode == "host":
-                fn = (jax.jit(_make_batched_step(mapped, num_queries))
-                      .lower(graph, state0, i0, h0).compile())
+                fn = jit(mapped).lower(graph, state0, i0).compile()
             elif mode == "fused":
-                fn = (jax.jit(_make_batched_fused_loop(
-                        mapped, registry, max_steps, check_overflow,
-                        num_queries))
-                      .lower(graph, state0, h0).compile())
+                fn = (
+                    jit(_make_fused_loop(mapped, registry, max_steps,
+                                             check_overflow))
+                    .lower(graph, state0)
+                    .compile()
+                )
             else:
-                fn = (jax.jit(_make_batched_chunk(
-                        mapped, registry, max_steps, check_overflow,
-                        chunk_size, num_queries))
-                      .lower(graph, state0, i0, h0, h0).compile())
-        elif mode == "host":
-            fn = jax.jit(mapped).lower(graph, state0, i0).compile()
-        elif mode == "fused":
-            fn = (
-                jax.jit(_make_fused_loop(mapped, registry, max_steps,
-                                         check_overflow))
-                .lower(graph, state0)
-                .compile()
-            )
-        else:
-            f = jnp.zeros((), bool)
-            fn = (
-                jax.jit(_make_chunk(mapped, registry, max_steps,
-                                    check_overflow, chunk_size))
-                .lower(graph, state0, i0, f, f)
-                .compile()
-            )
+                f = jnp.zeros((), bool)
+                fn = (
+                    jit(_make_chunk(mapped, registry, max_steps,
+                                        check_overflow, chunk_size))
+                    .lower(graph, state0, i0, f, f)
+                    .compile()
+                )
         compile_s = time.perf_counter() - tc
+        tracing.register(fn)
 
     # both validation directions without a dry trace: an undeclared
     # traced channel raised from add_traffic during the AOT trace above;
@@ -682,26 +692,30 @@ def _exec_host(stepper, graph, state0, max_steps, check_overflow) -> RunResult:
     step = -1  # so max_steps=0 reports zero executed supersteps
     for step in range(max_steps):
         ts = time.perf_counter()
-        state, halt_all, overflow, nbytes, nmsgs, novf = stepper(
-            graph, state, jnp.asarray(step, jnp.int32)
-        )
+        with tracing.span("job/dispatch"):
+            state, halt_all, overflow, nbytes, nmsgs, novf = stepper(
+                graph, state, jnp.asarray(step, jnp.int32)
+            )
         t_enq = time.perf_counter()
-        jax.block_until_ready(state)
+        with tracing.span("job/wait"):
+            jax.block_until_ready(state)
         t_dev = time.perf_counter()
         step_times.append(t_dev - ts)
-        for k, v in nbytes.items():
-            d = _host_int(v)
-            if d < 0:
-                wrapped_keys.add(k)
-            bytes_acc[k] = bytes_acc.get(k, 0) + d
-        for k, v in nmsgs.items():
-            d = _host_int(v)
-            if d < 0:
-                wrapped_keys.add(k)
-            msgs_acc[k] = msgs_acc.get(k, 0) + d
-        for k, v in novf.items():
-            ovf_acc[k] = ovf_acc.get(k, False) or bool(np.asarray(v).any())
-        halt_now = bool(np.asarray(halt_all).reshape(-1)[0])
+        with tracing.span("job/readback"):
+            for k, v in nbytes.items():
+                d = _host_int(v)
+                if d < 0:
+                    wrapped_keys.add(k)
+                bytes_acc[k] = bytes_acc.get(k, 0) + d
+            for k, v in nmsgs.items():
+                d = _host_int(v)
+                if d < 0:
+                    wrapped_keys.add(k)
+                msgs_acc[k] = msgs_acc.get(k, 0) + d
+            for k, v in novf.items():
+                ovf_acc[k] = (ovf_acc.get(k, False)
+                              or bool(np.asarray(v).any()))
+            halt_now = bool(np.asarray(halt_all).reshape(-1)[0])
         # dispatch enqueue plus readback/bookkeeping time: the host cost
         # of driving one step (the stepper is AOT-compiled, so step 0 is
         # an ordinary dispatch)
@@ -754,6 +768,7 @@ def _make_fused_loop(mapped, registry, max_steps, check_overflow):
     flags = registry.flags()
 
     def loop(graph, state):
+        @tracing.scope(tracing.VOTE)
         def cond(carry):
             _, i, halted, overflow, _, _, _, _ = carry
             go = (~halted) & (i < max_steps)
@@ -764,17 +779,20 @@ def _make_fused_loop(mapped, registry, max_steps, check_overflow):
         def body(carry):
             state, i, _, overflow, nb, nm, ovf_by, wrapped = carry
             new_state, halt, ovf, db, dm, dovf = mapped(graph, state, i)
-            nb2 = jax.tree_util.tree_map(jnp.add, nb, db)
-            nm2 = jax.tree_util.tree_map(jnp.add, nm, dm)
-            ovf_by2 = jax.tree_util.tree_map(jnp.logical_or, ovf_by, dovf)
-            # per-step deltas are non-negative, so a decreasing accumulator
-            # means the int32 counter wrapped — latch it for the host
-            for old, new in ((nb, nb2), (nm, nm2)):
-                for o, n in zip(jax.tree_util.tree_leaves(old),
-                                jax.tree_util.tree_leaves(new)):
-                    wrapped = wrapped | jnp.any(n < o)
-            return (new_state, i + 1, _scalar(halt),
-                    overflow | _scalar(ovf), nb2, nm2, ovf_by2, wrapped)
+            with tracing.scope(tracing.VOTE):
+                nb2 = jax.tree_util.tree_map(jnp.add, nb, db)
+                nm2 = jax.tree_util.tree_map(jnp.add, nm, dm)
+                ovf_by2 = jax.tree_util.tree_map(jnp.logical_or, ovf_by,
+                                                 dovf)
+                # per-step deltas are non-negative, so a decreasing
+                # accumulator means the int32 counter wrapped — latch it
+                # for the host
+                for old, new in ((nb, nb2), (nm, nm2)):
+                    for o, n in zip(jax.tree_util.tree_leaves(old),
+                                    jax.tree_util.tree_leaves(new)):
+                        wrapped = wrapped | jnp.any(n < o)
+                return (new_state, i + 1, _scalar(halt),
+                        overflow | _scalar(ovf), nb2, nm2, ovf_by2, wrapped)
 
         init = (state, jnp.asarray(0, jnp.int32), jnp.zeros((), bool),
                 jnp.zeros((), bool), zeros, zeros, flags,
@@ -786,18 +804,23 @@ def _make_fused_loop(mapped, registry, max_steps, check_overflow):
 
 def _exec_fused(compiled, graph, state0, check_overflow) -> RunResult:
     t0 = time.perf_counter()
-    out = compiled(graph, state0)
+    with tracing.span("job/dispatch"):
+        out = compiled(graph, state0)
     state, steps, halted, overflow, nb, nm, novf, wrapped = out
     t_enq = time.perf_counter()
-    jax.block_until_ready(state)
+    with tracing.span("job/wait"):
+        jax.block_until_ready(state)
     t_dev = time.perf_counter()
     wall = t_dev - t0
 
-    steps = int(np.asarray(steps))
-    halted_b = bool(np.asarray(halted))
-    bytes_by = {k: _host_int(v) for k, v in nb.items()}
-    msgs_by = {k: _host_int(v) for k, v in nm.items()}
-    ovf_by = {k: bool(np.asarray(v).any()) for k, v in novf.items()}
+    with tracing.span("job/readback"):
+        steps = int(np.asarray(steps))
+        halted_b = bool(np.asarray(halted))
+        bytes_by = {k: _host_int(v) for k, v in nb.items()}
+        msgs_by = {k: _host_int(v) for k, v in nm.items()}
+        ovf_by = {k: bool(np.asarray(v).any()) for k, v in novf.items()}
+        overflowed = bool(np.asarray(overflow))
+        wrapped = bool(np.asarray(wrapped))
     overhead = (t_enq - t0) + (time.perf_counter() - t_dev)
     res = RunResult(
         state=state,
@@ -813,12 +836,12 @@ def _exec_fused(compiled, graph, state0, check_overflow) -> RunResult:
         converged=halted_b,
         overflow_by_channel=ovf_by,
     )
-    if check_overflow and bool(np.asarray(overflow)):
+    if check_overflow and overflowed:
         bad = sorted(k for k, v in ovf_by.items() if v)
         raise errors.ChannelOverflowError(
             errors.overflow_message(steps - 1, bad),
             superstep=steps - 1, channels=bad, result=res)
-    if bool(np.asarray(wrapped)):
+    if wrapped:
         # the fused latch is global (accumulator decreased) — no
         # per-channel attribution on device
         raise errors.TrafficWrapError(
@@ -843,15 +866,17 @@ def _make_chunk(mapped, registry, max_steps, check_overflow, chunk_size):
     def chunk(graph, state, i0, halted0, overflow0):
         def body(carry, _):
             state, i, halted, overflow = carry
-            stop = halted | (i >= max_steps)
-            if check_overflow:
-                stop = stop | overflow
+            with tracing.scope(tracing.VOTE):
+                stop = halted | (i >= max_steps)
+                if check_overflow:
+                    stop = stop | overflow
 
             def do(operand):
                 state, i = operand
                 new_state, halt, ovf, db, dm, dovf = mapped(graph, state, i)
-                return ((new_state, i + 1, _scalar(halt),
-                         overflow | _scalar(ovf)), (db, dm, dovf))
+                with tracing.scope(tracing.VOTE):
+                    return ((new_state, i + 1, _scalar(halt),
+                             overflow | _scalar(ovf)), (db, dm, dovf))
 
             def skip(operand):
                 state, i = operand
@@ -901,19 +926,22 @@ def _make_batched_step(mapped, q: int):
     directly, fused/chunked call it from their loop bodies."""
 
     def bstep(graph, state, i, halted):
-        live = ~halted
+        with tracing.scope(tracing.VOTE):
+            live = ~halted
         new_state, halt, ovf, db, dm, dovf = mapped(graph, state, i, live)
-        new_state = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(_qmask(live, n), n, o), new_state, state)
-        # stat leaves have the query axis last ((W, Q) / (Q,)) — the
-        # (Q,) mask broadcasts; the halting step itself still charges
-        # (live is the PRE-step vote, matching Q independent runs)
-        db = jax.tree_util.tree_map(lambda d: jnp.where(live, d, 0), db)
-        dm = jax.tree_util.tree_map(lambda d: jnp.where(live, d, 0), dm)
-        dovf = jax.tree_util.tree_map(
-            lambda d: jnp.where(live, d, False), dovf)
-        return (new_state, halted | _qrow(halt, q),
-                _qrow(ovf, q) & live, db, dm, dovf)
+        with tracing.scope(tracing.VOTE):
+            new_state = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(_qmask(live, n), n, o), new_state,
+                state)
+            # stat leaves have the query axis last ((W, Q) / (Q,)) — the
+            # (Q,) mask broadcasts; the halting step itself still charges
+            # (live is the PRE-step vote, matching Q independent runs)
+            db = jax.tree_util.tree_map(lambda d: jnp.where(live, d, 0), db)
+            dm = jax.tree_util.tree_map(lambda d: jnp.where(live, d, 0), dm)
+            dovf = jax.tree_util.tree_map(
+                lambda d: jnp.where(live, d, False), dovf)
+            return (new_state, halted | _qrow(halt, q),
+                    _qrow(ovf, q) & live, db, dm, dovf)
 
     return bstep
 
@@ -927,6 +955,7 @@ def _make_batched_fused_loop(mapped, registry, max_steps, check_overflow, q):
     # start halted: a pad lane then never steps, never reaches the union
     # route pass (query_live=False end to end), and is never charged
     def loop(graph, state, halted0):
+        @tracing.scope(tracing.VOTE)
         def cond(carry):
             _, i, halted, overflow, _, _, _, _, _ = carry
             go = jnp.any(~halted) & (i < max_steps)
@@ -939,16 +968,18 @@ def _make_batched_fused_loop(mapped, registry, max_steps, check_overflow, q):
                 carry)
             new_state, halted2, ovf_q, db, dm, dovf = bstep(
                 graph, state, i, halted)
-            nb2 = jax.tree_util.tree_map(jnp.add, nb, db)
-            nm2 = jax.tree_util.tree_map(jnp.add, nm, dm)
-            ovf_by2 = jax.tree_util.tree_map(jnp.logical_or, ovf_by, dovf)
-            for old, new in ((nb, nb2), (nm, nm2)):
-                for o, n in zip(jax.tree_util.tree_leaves(old),
-                                jax.tree_util.tree_leaves(new)):
-                    wrapped = wrapped | jnp.any(n < o)
-            steps_q = steps_q + (~halted).astype(jnp.int32)
-            return (new_state, i + 1, halted2, overflow | ovf_q,
-                    steps_q, nb2, nm2, ovf_by2, wrapped)
+            with tracing.scope(tracing.VOTE):
+                nb2 = jax.tree_util.tree_map(jnp.add, nb, db)
+                nm2 = jax.tree_util.tree_map(jnp.add, nm, dm)
+                ovf_by2 = jax.tree_util.tree_map(jnp.logical_or, ovf_by,
+                                                 dovf)
+                for old, new in ((nb, nb2), (nm, nm2)):
+                    for o, n in zip(jax.tree_util.tree_leaves(old),
+                                    jax.tree_util.tree_leaves(new)):
+                        wrapped = wrapped | jnp.any(n < o)
+                steps_q = steps_q + (~halted).astype(jnp.int32)
+                return (new_state, i + 1, halted2, overflow | ovf_q,
+                        steps_q, nb2, nm2, ovf_by2, wrapped)
 
         qz = jnp.zeros((q,), bool)
         init = (state, jnp.asarray(0, jnp.int32), jnp.asarray(halted0, bool),
@@ -969,17 +1000,19 @@ def _make_batched_chunk(mapped, registry, max_steps, check_overflow,
     def chunk(graph, state, i0, halted0, overflow0):
         def body(carry, _):
             state, i, halted, overflow, steps_q = carry
-            stop = jnp.all(halted) | (i >= max_steps)
-            if check_overflow:
-                stop = stop | jnp.any(overflow)
+            with tracing.scope(tracing.VOTE):
+                stop = jnp.all(halted) | (i >= max_steps)
+                if check_overflow:
+                    stop = stop | jnp.any(overflow)
 
             def do(operand):
                 state, i, halted, overflow, steps_q = operand
                 new_state, halted2, ovf_q, db, dm, dovf = bstep(
                     graph, state, i, halted)
-                steps_q = steps_q + (~halted).astype(jnp.int32)
-                return ((new_state, i + 1, halted2, overflow | ovf_q,
-                         steps_q), (db, dm, dovf))
+                with tracing.scope(tracing.VOTE):
+                    steps_q = steps_q + (~halted).astype(jnp.int32)
+                    return ((new_state, i + 1, halted2, overflow | ovf_q,
+                             steps_q), (db, dm, dovf))
 
             def skip(operand):
                 return (operand, (zeros, zeros, flags))
@@ -1017,32 +1050,36 @@ def _make_serve_chunk(mapped, registry, max_steps, check_overflow,
     def chunk(graph, state, age0, halted0, overflow0):
         def body(carry, _):
             state, age, halted, overflow = carry
-            dead = halted | (age >= max_steps)
-            stop = jnp.all(dead)
-            if check_overflow:
-                stop = stop | jnp.any(overflow)
+            with tracing.scope(tracing.VOTE):
+                dead = halted | (age >= max_steps)
+                stop = jnp.all(dead)
+                if check_overflow:
+                    stop = stop | jnp.any(overflow)
 
             def do(operand):
                 state, age, halted, overflow = operand
-                live = ~(halted | (age >= max_steps))
+                with tracing.scope(tracing.VOTE):
+                    live = ~(halted | (age >= max_steps))
                 new_state, halt, ovf, db, dm, dovf = mapped(
                     graph, state, age, live)
-                new_state = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(_qmask(live, n), n, o),
-                    new_state, state)
-                db = jax.tree_util.tree_map(
-                    lambda d: jnp.where(live, d, 0), db)
-                dm = jax.tree_util.tree_map(
-                    lambda d: jnp.where(live, d, 0), dm)
-                dovf = jax.tree_util.tree_map(
-                    lambda d: jnp.where(live, d, False), dovf)
-                # only a live lane's own vote may halt it: a dead lane's
-                # (discarded) computation must not flip its flags
-                halted2 = halted | (_qrow(halt, q) & live)
-                overflow2 = overflow | (_qrow(ovf, q) & live)
-                return ((new_state, age + live.astype(jnp.int32),
-                         halted2, overflow2),
-                        (db, dm, dovf, live.astype(jnp.int32)))
+                with tracing.scope(tracing.VOTE):
+                    new_state = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(_qmask(live, n), n, o),
+                        new_state, state)
+                    db = jax.tree_util.tree_map(
+                        lambda d: jnp.where(live, d, 0), db)
+                    dm = jax.tree_util.tree_map(
+                        lambda d: jnp.where(live, d, 0), dm)
+                    dovf = jax.tree_util.tree_map(
+                        lambda d: jnp.where(live, d, False), dovf)
+                    # only a live lane's own vote may halt it: a dead
+                    # lane's (discarded) computation must not flip its
+                    # flags
+                    halted2 = halted | (_qrow(halt, q) & live)
+                    overflow2 = overflow | (_qrow(ovf, q) & live)
+                    return ((new_state, age + live.astype(jnp.int32),
+                             halted2, overflow2),
+                            (db, dm, dovf, live.astype(jnp.int32)))
 
             def skip(operand):
                 return (operand,
@@ -1130,22 +1167,25 @@ def _exec_batched(compiled, graph, state0, mode, max_steps, check_overflow,
     pad_halted = jnp.arange(q) >= q_real
     if mode == "fused":
         t0 = time.perf_counter()
-        out = compiled(graph, state0, pad_halted)
+        with tracing.span("job/dispatch"):
+            out = compiled(graph, state0, pad_halted)
         t_enq = time.perf_counter()
         state, steps, halted, overflow, steps_q, nb, nm, novf, wrapped = out
-        jax.block_until_ready(state)
+        with tracing.span("job/wait"):
+            jax.block_until_ready(state)
         t_dev = time.perf_counter()
         wall = t_dev - t0
         overhead = (t_enq - t0) + (time.perf_counter() - t_dev)
-        return _batched_result(
-            state, int(np.asarray(steps)), np.asarray(halted),
-            np.asarray(overflow),
-            {k: _host_q(v, q) for k, v in nb.items()},
-            {k: _host_q(v, q) for k, v in nm.items()},
-            np.asarray(steps_q).astype(np.int64), q_real, mode, 1, wall,
-            [wall], overhead, check_overflow,
-            ovf_by={k: _host_q_flag(v, q) for k, v in novf.items()},
-            wrapped=bool(np.asarray(wrapped)))
+        with tracing.span("job/readback"):
+            return _batched_result(
+                state, int(np.asarray(steps)), np.asarray(halted),
+                np.asarray(overflow),
+                {k: _host_q(v, q) for k, v in nb.items()},
+                {k: _host_q(v, q) for k, v in nm.items()},
+                np.asarray(steps_q).astype(np.int64), q_real, mode, 1, wall,
+                [wall], overhead, check_overflow,
+                ovf_by={k: _host_q_flag(v, q) for k, v in novf.items()},
+                wrapped=bool(np.asarray(wrapped)))
 
     q_bytes: Dict[str, np.ndarray] = {}
     q_msgs: Dict[str, np.ndarray] = {}
@@ -1181,19 +1221,22 @@ def _exec_batched(compiled, graph, state0, mode, max_steps, check_overflow,
             if not live.any():
                 break
             ts = time.perf_counter()
-            state, halted, ovf_q, db, dm, dovf = compiled(
-                graph, state, jnp.asarray(step, jnp.int32), halted)
+            with tracing.span("job/dispatch"):
+                state, halted, ovf_q, db, dm, dovf = compiled(
+                    graph, state, jnp.asarray(step, jnp.int32), halted)
             t_enq = time.perf_counter()
-            jax.block_until_ready(state)
+            with tracing.span("job/wait"):
+                jax.block_until_ready(state)
             t_dev = time.perf_counter()
             step_times.append(t_dev - ts)
             dispatches += 1
             steps = step + 1
             steps_q += live
-            acc(q_bytes, db)
-            acc(q_msgs, dm)
-            acc_ovf(dovf)
-            overflow_acc |= np.asarray(ovf_q)
+            with tracing.span("job/readback"):
+                acc(q_bytes, db)
+                acc(q_msgs, dm)
+                acc_ovf(dovf)
+                overflow_acc |= np.asarray(ovf_q)
             overhead += (t_enq - ts) + (time.perf_counter() - t_dev)
             if check_overflow and overflow_acc[:q_real].any():
                 break
@@ -1204,19 +1247,22 @@ def _exec_batched(compiled, graph, state0, mode, max_steps, check_overflow,
         overflow = jnp.zeros((q,), bool)
         while True:
             ts = time.perf_counter()
-            state, i, halted, overflow, d_steps, db, dm, dovf = compiled(
-                graph, state, i, halted, overflow)
+            with tracing.span("job/dispatch"):
+                state, i, halted, overflow, d_steps, db, dm, dovf = compiled(
+                    graph, state, i, halted, overflow)
             t_enq = time.perf_counter()
-            jax.block_until_ready(state)
+            with tracing.span("job/wait"):
+                jax.block_until_ready(state)
             t_dev = time.perf_counter()
             step_times.append(t_dev - ts)
             dispatches += 1
-            steps = int(np.asarray(i))
-            steps_q += np.asarray(d_steps).astype(np.int64)
-            acc(q_bytes, db)
-            acc(q_msgs, dm)
-            acc_ovf(dovf)
-            overflow_acc |= np.asarray(overflow)
+            with tracing.span("job/readback"):
+                steps = int(np.asarray(i))
+                steps_q += np.asarray(d_steps).astype(np.int64)
+                acc(q_bytes, db)
+                acc(q_msgs, dm)
+                acc_ovf(dovf)
+                overflow_acc |= np.asarray(overflow)
             overhead += (t_enq - ts) + (time.perf_counter() - t_dev)
             if check_overflow and overflow_acc[:q_real].any():
                 break
@@ -1263,29 +1309,33 @@ def _exec_chunked(compiled, graph, state0, max_steps, check_overflow,
     t0 = time.perf_counter()
     while True:
         ts = time.perf_counter()
-        state, i, halted, overflow, db, dm, dovf = compiled(
-            graph, state, i, halted, overflow
-        )
+        with tracing.span("job/dispatch"):
+            state, i, halted, overflow, db, dm, dovf = compiled(
+                graph, state, i, halted, overflow
+            )
         t_enq = time.perf_counter()
-        jax.block_until_ready(state)
+        with tracing.span("job/wait"):
+            jax.block_until_ready(state)
         t_dev = time.perf_counter()
         chunk_times.append(t_dev - ts)
         dispatches += 1
         # stream the chunk's per-step stats out (skipped steps are zero);
         # a negative per-step delta is an in-step int32 wrap
-        for k, v in db.items():
-            if (np.asarray(v) < 0).any():
-                wrapped_keys.add(k)
-            bytes_acc[k] = bytes_acc.get(k, 0) + _host_int(v)
-        for k, v in dm.items():
-            if (np.asarray(v) < 0).any():
-                wrapped_keys.add(k)
-            msgs_acc[k] = msgs_acc.get(k, 0) + _host_int(v)
-        for k, v in dovf.items():
-            ovf_acc[k] = ovf_acc.get(k, False) or bool(np.asarray(v).any())
-        steps = int(np.asarray(i))
-        halt_now = bool(np.asarray(halted))
-        overflowed = check_overflow and bool(np.asarray(overflow))
+        with tracing.span("job/readback"):
+            for k, v in db.items():
+                if (np.asarray(v) < 0).any():
+                    wrapped_keys.add(k)
+                bytes_acc[k] = bytes_acc.get(k, 0) + _host_int(v)
+            for k, v in dm.items():
+                if (np.asarray(v) < 0).any():
+                    wrapped_keys.add(k)
+                msgs_acc[k] = msgs_acc.get(k, 0) + _host_int(v)
+            for k, v in dovf.items():
+                ovf_acc[k] = (ovf_acc.get(k, False)
+                              or bool(np.asarray(v).any()))
+            steps = int(np.asarray(i))
+            halt_now = bool(np.asarray(halted))
+            overflowed = check_overflow and bool(np.asarray(overflow))
         overhead += (t_enq - ts) + (time.perf_counter() - t_dev)
         if overflowed or wrapped_keys:
             break

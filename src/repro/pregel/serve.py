@@ -33,9 +33,11 @@ with ``status="overflow"`` (no output, the offending channel names on
 still matches its solo run bit for bit. :class:`FaultSpec` injects
 deterministic failures (forced overflow / forced step-budget exhaustion
 on a chosen qid at a chosen per-query step) so the isolation contract is
-drillable without crafting a pathological graph; a
-:class:`~repro.distributed.fault_tolerance.StragglerMonitor` watches
-per-dispatch wall times and reports outlier dispatches on the result.
+drillable without crafting a pathological graph.
+
+Every boundary step runs under a host span (``repro.pregel.tracing``):
+``serve/admit``, ``serve/dispatch``, ``serve/wait``, ``serve/readback``,
+``serve/account`` and ``serve/harvest``.
 """
 from __future__ import annotations
 
@@ -48,9 +50,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.fault_tolerance import StragglerMonitor
 from repro.pregel import errors
 from repro.pregel import runtime
+from repro.pregel import tracing
 
 
 def poisson_arrivals(n: int, rate: float, seed: int = 0) -> List[int]:
@@ -257,9 +259,7 @@ class ServeResult:
     # (repro.plan.Plan; data-plane knobs only — the serve substrate pins
     # mode/chunk itself)
     plan: Any = None
-    # dispatch indices whose wall time the StragglerMonitor flagged as
-    # outliers (> threshold x rolling median), plus the session median
-    straggler_dispatches: List[int] = dataclasses.field(default_factory=list)
+    # median wall time of one dispatch, enqueue to block_until_ready
     dispatch_median_s: float = 0.0
 
     @property
@@ -362,8 +362,7 @@ def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
     records: List[QueryRecord] = []
     sess_bytes: Dict[str, int] = {}
     sess_msgs: Dict[str, int] = {}
-    monitor = StragglerMonitor()
-    stragglers: List[int] = []
+    dispatch_times: List[float] = []
     clock = 0
     executed = 0
     dispatches = 0
@@ -371,26 +370,28 @@ def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
     now = lambda: time.perf_counter() - t0
 
     while True:
-        queue.mark_eligible(clock, now())
-        # --- admission: FIFO by (arrival, qid) into the lowest free lane
-        for lane in range(L):
-            if occupant[lane] is not None:
-                continue
-            entry = queue.pop_ready(clock)
-            if entry is None:
-                break
-            state = _write_lane(state, prog.query_init(pg, entry.query),
-                                np.int32(lane))
-            age[lane] = 0
-            halted[lane] = False
-            overflow[lane] = False
-            occupant[lane] = QueryRecord(
-                qid=entry.qid, query=entry.query, lane=lane,
-                arrival=entry.arrival, admitted=clock,
-                wall_eligible_s=(entry.wall_eligible_s
-                                 if entry.wall_eligible_s is not None
-                                 else now()),
-                wall_admitted_s=now())
+        with tracing.span("serve/admit"):
+            queue.mark_eligible(clock, now())
+            # --- admission: FIFO by (arrival, qid) into the lowest free
+            # lane
+            for lane in range(L):
+                if occupant[lane] is not None:
+                    continue
+                entry = queue.pop_ready(clock)
+                if entry is None:
+                    break
+                state = _write_lane(state, prog.query_init(pg, entry.query),
+                                    np.int32(lane))
+                age[lane] = 0
+                halted[lane] = False
+                overflow[lane] = False
+                occupant[lane] = QueryRecord(
+                    qid=entry.qid, query=entry.query, lane=lane,
+                    arrival=entry.arrival, admitted=clock,
+                    wall_eligible_s=(entry.wall_eligible_s
+                                     if entry.wall_eligible_s is not None
+                                     else now()),
+                    wall_admitted_s=now())
 
         if all(r is None for r in occupant):
             nxt = queue.next_arrival()
@@ -401,96 +402,102 @@ def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
 
         # --- one chunk: up to chunk_size supersteps, all live lanes
         t_disp = time.perf_counter()
-        state, age_j, halted_j, overflow_j, d_steps, db, dm, dovf = \
-            exe.serve_chunk(graph, state, age, halted, overflow)
-        jax.block_until_ready(state)
-        if monitor.record(dispatches, time.perf_counter() - t_disp):
-            stragglers.append(dispatches)
+        with tracing.span("serve/dispatch"):
+            state, age_j, halted_j, overflow_j, d_steps, db, dm, dovf = \
+                exe.serve_chunk(graph, state, age, halted, overflow)
+        with tracing.span("serve/wait"):
+            jax.block_until_ready(state)
+        dispatch_times.append(time.perf_counter() - t_disp)
         dispatches += 1
-        # host-side writable copies: admission/harvest mutate them in place
-        age = np.array(age_j)
-        halted = np.array(halted_j)
-        overflow = np.array(overflow_j)
-        d_steps = np.asarray(d_steps).astype(np.int64)
+        with tracing.span("serve/readback"):
+            # host-side writable copies: admission/harvest mutate them in
+            # place
+            age = np.array(age_j)
+            halted = np.array(halted_j)
+            overflow = np.array(overflow_j)
+            d_steps = np.asarray(d_steps).astype(np.int64)
         steps_run = int(d_steps.max()) if L else 0
         clock += steps_run
         executed += steps_run
 
-        # --- per-tenancy accounting: this chunk's stats belong to the
-        # lanes' current occupants (admission only happens at boundaries,
-        # so a chunk is never split across tenancies)
-        occupied = [l for l in range(L) if occupant[l] is not None]
-        for acc, per_lane, delta in ((sess_bytes, "bytes_by_channel", db),
-                                     (sess_msgs, "msgs_by_channel", dm)):
-            for name, v in delta.items():
-                row = runtime._host_q(v, L)
-                acc[name] = acc.get(name, 0) + int(row.sum())
-                for lane in occupied:
-                    d = getattr(occupant[lane], per_lane)
-                    d[name] = d.get(name, 0) + int(row[lane])
-        for lane in occupied:
-            occupant[lane].steps += int(d_steps[lane])
+        with tracing.span("serve/account"):
+            # --- per-tenancy accounting: this chunk's stats belong to the
+            # lanes' current occupants (admission only happens at
+            # boundaries, so a chunk is never split across tenancies)
+            occupied = [l for l in range(L) if occupant[l] is not None]
+            for acc, per_lane, delta in (
+                    (sess_bytes, "bytes_by_channel", db),
+                    (sess_msgs, "msgs_by_channel", dm)):
+                for name, v in delta.items():
+                    row = runtime._host_q(v, L)
+                    acc[name] = acc.get(name, 0) + int(row.sum())
+                    for lane in occupied:
+                        d = getattr(occupant[lane], per_lane)
+                        d[name] = d.get(name, 0) + int(row[lane])
+            for lane in occupied:
+                occupant[lane].steps += int(d_steps[lane])
 
-        # --- fault injection: force failures due at this boundary
-        for lane in occupied:
-            rec = occupant[lane]
-            spec = fault_by_qid.get(rec.qid)
-            if (spec is not None and spec.kind == "overflow"
-                    and not rec.injected and rec.steps >= spec.at_step):
-                overflow[lane] = True
-                rec.injected = True
-
-        # --- quarantine (or raise) lanes that overflowed a channel
-        ovf_lanes = [l for l in occupied
-                     if overflow[l]
-                     and (check_overflow or occupant[l].injected)]
-        if ovf_lanes:
-            chan_flags = {name: runtime._host_q_flag(v, L)
-                          for name, v in dovf.items()}
-            if on_fault == "raise":
-                bad = [occupant[l].qid for l in ovf_lanes]
-                chans = sorted(
-                    n for n, row in chan_flags.items()
-                    if any(row[l] for l in ovf_lanes))
-                raise errors.ChannelOverflowError(
-                    errors.overflow_message(clock, chans, qids=bad),
-                    superstep=clock, channels=chans, qids=bad)
-            for lane in ovf_lanes:
+            # --- fault injection: force failures due at this boundary
+            for lane in occupied:
                 rec = occupant[lane]
-                rec.status = "overflow"
-                rec.channels = tuple(sorted(
-                    n for n, row in chan_flags.items() if row[lane]))
-                rec.output = None
-                rec.halted = False
+                spec = fault_by_qid.get(rec.qid)
+                if (spec is not None and spec.kind == "overflow"
+                        and not rec.injected and rec.steps >= spec.at_step):
+                    overflow[lane] = True
+                    rec.injected = True
+
+            # --- quarantine (or raise) lanes that overflowed a channel
+            ovf_lanes = [l for l in occupied
+                         if overflow[l]
+                         and (check_overflow or occupant[l].injected)]
+            if ovf_lanes:
+                chan_flags = {name: runtime._host_q_flag(v, L)
+                              for name, v in dovf.items()}
+                if on_fault == "raise":
+                    bad = [occupant[l].qid for l in ovf_lanes]
+                    chans = sorted(
+                        n for n, row in chan_flags.items()
+                        if any(row[l] for l in ovf_lanes))
+                    raise errors.ChannelOverflowError(
+                        errors.overflow_message(clock, chans, qids=bad),
+                        superstep=clock, channels=chans, qids=bad)
+                for lane in ovf_lanes:
+                    rec = occupant[lane]
+                    rec.status = "overflow"
+                    rec.channels = tuple(sorted(
+                        n for n, row in chan_flags.items() if row[lane]))
+                    rec.output = None
+                    rec.halted = False
+                    rec.finished = clock
+                    rec.wall_finished_s = now()
+                    records.append(rec)
+                    occupant[lane] = None
+                    halted[lane] = True   # dead until refilled (state slice
+                    overflow[lane] = False  # is rewritten on admission)
+
+        with tracing.span("serve/harvest"):
+            # --- harvest: lanes whose query halted or ran out of budget
+            # (or whose FaultSpec exhausts it early)
+            for lane in occupied:
+                rec = occupant[lane]
+                if rec is None:
+                    continue              # quarantined above
+                spec = fault_by_qid.get(rec.qid)
+                force = (spec is not None and spec.kind == "exhaust"
+                         and rec.steps >= spec.at_step)
+                if not (halted[lane] or age[lane] >= max_steps or force):
+                    continue
+                lane_state = jax.tree_util.tree_map(
+                    lambda leaf, _l=lane: leaf[:, _l], state)
+                rec.output = prog.extract(pg, lane_state)
+                rec.halted = bool(halted[lane])
+                rec.status = "ok" if rec.halted else "exhausted"
+                rec.injected = rec.injected or (force and not rec.halted)
                 rec.finished = clock
                 rec.wall_finished_s = now()
                 records.append(rec)
                 occupant[lane] = None
-                halted[lane] = True   # dead until refilled (state slice
-                overflow[lane] = False  # is rewritten on admission)
-
-        # --- harvest: lanes whose query halted or ran out of budget
-        # (or whose FaultSpec exhausts it early)
-        for lane in occupied:
-            rec = occupant[lane]
-            if rec is None:
-                continue              # quarantined above
-            spec = fault_by_qid.get(rec.qid)
-            force = (spec is not None and spec.kind == "exhaust"
-                     and rec.steps >= spec.at_step)
-            if not (halted[lane] or age[lane] >= max_steps or force):
-                continue
-            lane_state = jax.tree_util.tree_map(
-                lambda leaf, _l=lane: leaf[:, _l], state)
-            rec.output = prog.extract(pg, lane_state)
-            rec.halted = bool(halted[lane])
-            rec.status = "ok" if rec.halted else "exhausted"
-            rec.injected = rec.injected or (force and not rec.halted)
-            rec.finished = clock
-            rec.wall_finished_s = now()
-            records.append(rec)
-            occupant[lane] = None
-            halted[lane] = True      # lane is dead until refilled
+                halted[lane] = True      # lane is dead until refilled
 
     records.sort(key=lambda r: r.qid)
     return ServeResult(
@@ -505,6 +512,6 @@ def serve_loop(exe, prog, pg, state0, queue: QueryQueue, num_lanes: int,
         wall_time_s=time.perf_counter() - t0,
         bytes_by_channel=sess_bytes,
         msgs_by_channel=sess_msgs,
-        straggler_dispatches=stragglers,
-        dispatch_median_s=monitor.median,
+        dispatch_median_s=(float(np.median(dispatch_times))
+                           if dispatch_times else 0.0),
     )

@@ -377,11 +377,12 @@ def test_serve_quarantined_lane_is_recycled():
 
 
 def test_serve_straggler_monitor_reports():
+    """The session reports the median wall time of its dispatches."""
     pg, prog, queries = serve_problem()
     res = Engine(mode="chunked", chunk_size=3).serve(
         prog, pg, queries, num_lanes=3)
-    assert isinstance(res.straggler_dispatches, list)
-    assert res.dispatch_median_s >= 0.0
+    assert res.dispatches > 0
+    assert 0.0 < res.dispatch_median_s <= res.wall_time_s
 
 
 def test_fault_spec_validation():
