@@ -98,11 +98,11 @@ def bench_combine(scale: int, repeats: int):
 
     ref_fn = jax.jit(lambda v, s: kref.segment_combine_ref(
         v, s, plan.u_cap, "sum"))
-    chunk_plan = (plan.chunk_start[0], plan.chunk_count[0], plan.max_chunks)
+    work_list = (plan.item_block[0], plan.item_chunk[0])
     kern_fn = jax.jit(lambda v, s: kops.segment_combine(
         v, s, plan.u_cap, "sum", use_kernel=True, assume_sorted=True,
         block_rows=plan.block_rows, block_edges=plan.block_edges,
-        chunk_plan=chunk_plan))
+        work_list=work_list))
 
     t_ref = _time(ref_fn, vals, seg, repeats=repeats)
     t_kern = _time(kern_fn, vals, seg, repeats=repeats)

@@ -145,14 +145,14 @@ def phase_kernels(seed: int) -> None:
             f"(first call {dt:.3f}s)")
 
     # scatter-combine: E sorted edges into N segments, with the host
-    # chunk tables a ScatterPlan carries. The f32 sum adds small integers,
+    # work list a ScatterPlan carries. The f32 sum adds small integers,
     # which is exact in any order, so it too must match bit for bit.
     n, e = KERNEL_SEGMENTS, KERNEL_EDGES
     seg_np = np.sort(rng.integers(0, n, e)).astype(np.int32)
     br, be = kops.autotune_block_sizes(n, e)
-    cs, nc, mx = kops.plan_chunks(seg_np, n, br, be)
     seg = jnp.asarray(seg_np)
-    plan = (jnp.asarray(cs), jnp.asarray(nc), mx)
+    plan = tuple(map(jnp.asarray, kops.build_work_list(
+        *kops.plan_chunks(seg_np, n, br, be))))
     cases = (
         ("segment_combine int32 min", "min",
          rng.integers(0, n, (e, 1)).astype(np.int32)),
@@ -165,7 +165,7 @@ def phase_kernels(seed: int) -> None:
         vals = jnp.asarray(vals_np)
         kern = jax.jit(lambda v, s, _c=comb: kops.segment_combine(
             v, s, n, _c, assume_sorted=True, block_rows=br,
-            block_edges=be, chunk_plan=plan))
+            block_edges=be, work_list=plan))
         got, dt = timed(kern, vals, seg)
         want = jax.jit(lambda v, s, _c=comb: kref.segment_combine_ref(
             v, s, n, _c))(vals, seg)

@@ -81,14 +81,14 @@ def plan_broadcast_combine(
 
     # 2. sender-side combine: one value per unique destination (sorted
     # ids). The kernel path rides the plan's autotuned block sizes and
-    # precomputed chunk tables (graph/pgraph.py) instead of deriving a
-    # worst-case grid on device.
+    # precomputed work list (graph/pgraph.py) instead of deriving a
+    # worst-case one on device.
     kernel_kw = {}
-    if plan.chunk_start is not None:
+    if plan.item_block is not None:
         kernel_kw = dict(
             block_rows=plan.block_rows,
             block_edges=plan.block_edges,
-            chunk_plan=(plan.chunk_start, plan.chunk_count, plan.max_chunks),
+            work_list=(plan.item_block, plan.item_chunk),
         )
     u_vals = kops.segment_combine(
         per_edge, plan.edge_seg, plan.u_cap, combiner,

@@ -82,9 +82,11 @@ class ScatterPlan:
     send_count: jax.Array    # (W, W) i32 real entries per peer
     # autotuned segment-combine kernel plan (host-built from the edge
     # distribution; the statics ride the treedef, so the block choice is
-    # part of every compile-cache key that includes this plan)
-    chunk_start: Optional[jax.Array]  # (W, NB) i32 first covering chunk
-    chunk_count: Optional[jax.Array]  # (W, NB) i32 covering chunks per block
+    # part of every compile-cache key that includes this plan): the
+    # kernel's flat work list, one (row block, chunk) item per grid step
+    # (repro.kernels.ops.build_work_list)
+    item_block: Optional[jax.Array]  # (W, T) i32 row block per item
+    item_chunk: Optional[jax.Array]  # (W, T) i32 chunk per item (~c: none)
     # static metadata
     n_loc: int = dataclasses.field(metadata=dict(static=True))
     num_workers: int = dataclasses.field(metadata=dict(static=True))
@@ -95,7 +97,6 @@ class ScatterPlan:
     total_edges: int = dataclasses.field(metadata=dict(static=True))
     block_rows: int = dataclasses.field(default=0, metadata=dict(static=True))
     block_edges: int = dataclasses.field(default=0, metadata=dict(static=True))
-    max_chunks: int = dataclasses.field(default=0, metadata=dict(static=True))
     # hub mirroring (partition_graph(mirror_threshold=...)): cut edges
     # whose source degree exceeds the threshold are *re-homed* to the
     # destination owner and combined there (mirror-side pre-combine). The
@@ -108,6 +109,20 @@ class ScatterPlan:
     hub_cap: int = dataclasses.field(default=0, metadata=dict(static=True))
     mirrored_edges: int = dataclasses.field(
         default=0, metadata=dict(static=True))
+
+    @property
+    def grid_steps(self) -> int:
+        """Grid steps of one worker's segment-combine kernel call (static:
+        the work list's length)."""
+        return 0 if self.item_block is None else self.item_block.shape[-1]
+
+    @property
+    def active_items(self) -> np.ndarray:
+        """(W,) work items per worker that combine a chunk; the rest of
+        :attr:`grid_steps` only set empty blocks or pad (host read-back)."""
+        if self.item_chunk is None:
+            return np.zeros(self.num_workers, np.int64)
+        return (np.asarray(self.item_chunk) >= 0).sum(axis=-1)
 
 
 @jax.tree_util.register_dataclass
@@ -288,25 +303,24 @@ def _build_scatter_plan(
             recv_local[p, w, : len(mine)] = (mine - p * n_loc).astype(np.int32)
 
     # autotuned segment-combine block plan: block sizes chosen from the
-    # edge distribution, per-worker chunk tables built against the
-    # kernel's padded view (repro.kernels.ops.plan_chunks). Imported
-    # lazily: the kernels package pulls in repro.core, which imports the
-    # channel modules that import this one.
+    # edge distribution, per-worker work lists built against the kernel's
+    # padded view (repro.kernels.ops.plan_chunks). Imported lazily: the
+    # kernels package pulls in repro.core, which imports the channel
+    # modules that import this one.
     from repro.kernels import ops as kops
 
     block_rows, block_edges = kops.autotune_block_sizes(u_cap, e_cap)
-    chunk_start, chunk_count, max_chunks = [], [], 0
-    for w in range(W):
-        cs, nc, mx = kops.plan_chunks(
-            edge_seg[w], u_cap, block_rows, block_edges
-        )
-        chunk_start.append(cs)
-        chunk_count.append(nc)
-        max_chunks = max(max_chunks, mx)
-    # max_chunks is a static grid bound derived from the edge *skew*, not
-    # the caps — bucket it to the next power of two so same-cap graphs
-    # with slightly different skew still share a compile signature
-    max_chunks = _bucket_cap(max_chunks, 1)
+    bounds = [kops.plan_chunks(edge_seg[w], u_cap, block_rows, block_edges)
+              for w in range(W)]
+    # The work list's length T is the kernel's grid, a static shape set
+    # by the edge *skew*, not the caps: the heaviest worker's item count,
+    # bucketed to the next power of two (capped at the bound NB + EC) so
+    # same-cap graphs with slightly different skew share a compile
+    # signature. Lighter workers pad with items that combine nothing.
+    start, _, ec = bounds[0]
+    n_items = max(len(kops.build_work_list(*b)[0]) for b in bounds)
+    n_items = min(_bucket_cap(n_items, 1), len(start) + ec)
+    work = [kops.build_work_list(*b, n_items) for b in bounds]
 
     return ScatterPlan(
         edge_src=jnp.asarray(edge_src),
@@ -315,8 +329,8 @@ def _build_scatter_plan(
         pack_slot=jnp.asarray(pack_slot),
         recv_local=jnp.asarray(recv_local),
         send_count=jnp.asarray(send_count),
-        chunk_start=jnp.asarray(np.stack(chunk_start)),
-        chunk_count=jnp.asarray(np.stack(chunk_count)),
+        item_block=jnp.asarray(np.stack([blk for blk, _ in work])),
+        item_chunk=jnp.asarray(np.stack([chunk for _, chunk in work])),
         n_loc=n_loc,
         num_workers=W,
         e_cap=e_cap,
@@ -326,7 +340,6 @@ def _build_scatter_plan(
         total_edges=total,
         block_rows=block_rows,
         block_edges=block_edges,
-        max_chunks=max_chunks,
         hub_local=(jnp.asarray(hub_local_np)
                    if hub_local_np is not None else None),
         hub_cap=hub_cap,

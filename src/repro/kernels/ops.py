@@ -90,33 +90,55 @@ def autotune_block_sizes(u_cap: int, e_cap: int) -> Tuple[int, int]:
     return block_rows, block_edges
 
 
-def build_chunk_plan(seg_ids_np, num_segments, block_rows, block_edges):
-    """Host-side (numpy) plan: covering chunk range per output row block.
-
-    Returns (chunk_start, num_chunks, max_chunks) for sorted seg_ids.
-    """
-    seg = np.asarray(seg_ids_np)
-    nb = _round_up(num_segments, block_rows) // block_rows
-    bounds = np.searchsorted(seg, np.arange(nb + 1) * block_rows, side="left")
+def chunk_bounds(seg_ids, num_row_blocks, block_rows, block_edges, xp=np):
+    """First covering chunk and covering-chunk count of each row block
+    for sorted seg_ids (numpy on the host, ``xp=jnp`` on the device)."""
+    bounds = xp.searchsorted(
+        seg_ids, xp.arange(num_row_blocks + 1) * block_rows, side="left")
     lo, hi = bounds[:-1], bounds[1:]
-    cs = lo // block_edges
-    ce = -(-hi // block_edges)  # ceil
-    nc = np.where(hi > lo, ce - cs, 0).astype(np.int32)
-    return cs.astype(np.int32), nc, int(nc.max(initial=0))
+    start = lo // block_edges
+    count = xp.where(hi > lo, -(-hi // block_edges) - start, 0)
+    return start, count
+
+
+def build_work_list(start, count, num_chunks, n_items=None, xp=np):
+    """The kernel's flat work list from :func:`chunk_bounds`:
+    (item_block, item_chunk) int32 of length ``n_items``. Items run in
+    row-block order, each block's chunks ascending, and a block with no
+    covering chunk gets one item (it still sets its tile to the
+    identity): ``sum(max(count, 1))`` items, the default length (numpy
+    only), below ``NB + EC`` since neighbouring blocks share at most one
+    chunk. An item that combines nothing (an empty block's, or trailing
+    padding, which repeats the last real item) carries its chunk ``c`` as
+    ``~c``. Built in numpy for a plan, with ``xp=jnp`` on the device."""
+    if n_items is None:
+        n_items = int(np.maximum(count, 1).sum())
+    per = xp.maximum(count, 1)
+    ends = xp.cumsum(per)
+    t = xp.arange(n_items)
+    blk = xp.minimum(xp.searchsorted(ends, t, side="right"), len(per) - 1)
+    j = xp.minimum(t - (ends[blk] - per[blk]), per[blk] - 1)
+    chunk = xp.clip(start[blk] + j, 0, num_chunks - 1)
+    real = (j < count[blk]) & (t < ends[-1])
+    return (blk.astype(xp.int32),
+            xp.where(real, chunk, ~chunk).astype(xp.int32))
 
 
 def plan_chunks(seg_ids_np, num_segments, block_rows, block_edges):
-    """build_chunk_plan against the *kernel's* padded view of the inputs:
-    entries >= num_segments map to the padded row bound and the edge axis
-    is padded to a block_edges multiple — exactly what
-    :func:`segment_combine` does internally, so a plan built here can be
-    passed as its ``chunk_plan`` (the ScatterPlan autotune path)."""
+    """:func:`chunk_bounds` on the host against the *kernel's* padded view
+    of the inputs, as :func:`segment_combine` builds it (entries outside
+    ``[0, num_segments)`` map to the padded row bound, the edge axis is
+    padded to a ``block_edges`` multiple), so :func:`build_work_list` of
+    the result can be passed as its ``work_list``. Returns (start, count,
+    num_chunks)."""
     seg = np.asarray(seg_ids_np)
     n_pad = _round_up(max(num_segments, 1), block_rows)
     e_pad = _round_up(max(len(seg), 1), block_edges)
     seg = np.where((seg < 0) | (seg >= num_segments), n_pad, seg)
     seg = np.concatenate([seg, np.full(e_pad - len(seg), n_pad, seg.dtype)])
-    return build_chunk_plan(seg, num_segments, block_rows, block_edges)
+    start, count = chunk_bounds(seg, n_pad // block_rows, block_rows,
+                                block_edges)
+    return start, count, e_pad // block_edges
 
 
 # ---------------------------------------------------------------------------
@@ -135,14 +157,17 @@ def segment_combine(
     interpret: Optional[bool] = None,
     block_rows: int = 128,
     block_edges: int = 512,
-    chunk_plan=None,
+    work_list=None,
     assume_sorted: bool = False,
 ):
     """Segment reduction: out[s] = combine(vals[e] for seg_ids[e] == s).
 
     Entries with seg_ids >= num_segments are dropped. The kernel path
-    requires sorted seg_ids (assume_sorted or it sorts internally). Both
-    paths run under the ``combine`` device scope.
+    requires sorted seg_ids (assume_sorted or it sorts internally) and
+    runs over ``work_list``, a plan's (item_block, item_chunk)
+    (:func:`build_work_list` of :func:`plan_chunks`), or one built on the
+    device at the bound NB + EC. Both paths run under the ``combine``
+    device scope.
     """
     combiner = cb.get(combiner)
     if not resolve_use_kernel(use_kernel):
@@ -174,29 +199,22 @@ def segment_combine(
         (seg_ids < 0) | (seg_ids >= num_segments), n_pad, seg_ids
     )
 
-    if chunk_plan is None:
-        nb = n_pad // block_rows
-        bounds = jnp.searchsorted(
-            seg_ids, jnp.arange(nb + 1, dtype=jnp.int32) * block_rows, side="left"
-        )
-        lo, hi = bounds[:-1], bounds[1:]
-        cs = lo // block_edges
-        ce = -((-hi) // block_edges)
-        nc = jnp.where(hi > lo, ce - cs, 0).astype(jnp.int32)
-        max_chunks = e_pad // block_edges  # static worst case
-    else:
-        cs, nc, max_chunks = chunk_plan
+    if work_list is None:
+        nb, ec = n_pad // block_rows, e_pad // block_edges
+        start, count = chunk_bounds(seg_ids, nb, block_rows, block_edges,
+                                    xp=jnp)
+        work_list = build_work_list(start, count, ec, nb + ec, xp=jnp)
+    item_block, item_chunk = work_list
 
     out = kseg.segment_combine_pallas(
         vals,
         seg_ids,
-        cs,
-        nc,
+        item_block,
+        item_chunk,
         num_segments=n_pad,
         combiner=combiner,
         block_rows=block_rows,
         block_edges=block_edges,
-        max_chunks=max_chunks,
         interpret=resolve_interpret(interpret),
     )[:num_segments]
     return out[:, 0] if squeeze else out

@@ -9,8 +9,9 @@ same preprocessing yields a *block-CSR segment reduction*:
   - the edge array (values + segment ids, already sorted by segment) is
     tiled into chunks of ``block_edges``, each laid out lane-dense as
     ``(rows, lanes)`` in edge order (``repro.kernels.layout``),
-  - a host-side plan maps each row block to its covering chunk range
-    (scalar-prefetched, the standard block-sparse index-table pattern),
+  - a host-side plan lists each row block's covering chunks as work
+    items (scalar-prefetched, the standard block-sparse index-table
+    pattern; see Grid below),
   - inside the kernel each edge row of a chunk is reduced by a masked
     select and a lane reduction: the ``(block_rows, lanes)`` mask
     ``seg == row`` picks each output row's edges, everything else holds
@@ -23,10 +24,19 @@ generation. Partials combine across chunks with the same combiner: for
 ``min``/``max`` and integer ``sum`` the result is exactly the
 reference's, and a float ``sum`` differs from it in rounding order only.
 
-Grid: (num_row_blocks, max_chunks_per_block); the output tile is revisited
-across the chunk axis and initialized at chunk 0 — the canonical Pallas
-reduction pattern. Blocks whose chunk index exceeds their chunk count are
-skipped with ``pl.when``.
+Grid: one step per *work item*, a flat list of (row block, covering
+chunk) pairs in row-block order with each block's chunks ascending (the
+ragged-grid pattern of grouped-matmul kernels). Two scalar-prefetched
+tables drive it: ``item_block[t]`` picks the output tile and
+``item_chunk[t]`` the input chunk, so the revisits of one output tile are
+consecutive — the canonical Pallas reduction — and the tile is
+initialized on the first item of its block. A block with no edges still
+gets one item (its tile is set to the identity); such an item, and the
+trailing padding items that repeat the last real one, carry their chunk
+``c`` as ``~c`` (= -(c + 1)): the index map fetches ``c`` (the chunk
+already resident, or the next block's first, so nothing is fetched
+twice) and the combine is skipped. Neighbouring blocks share at most one
+covering chunk, so a call never needs more than ``NB + EC`` items.
 """
 from __future__ import annotations
 
@@ -45,19 +55,19 @@ from repro.kernels.layout import chunk_tile, col_to_row
 _REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
 
 
-def _kernel(cs_ref, nc_ref, seg_ref, vals_ref, o_ref, *, combiner,
+def _kernel(ib_ref, ic_ref, seg_ref, vals_ref, o_ref, *, combiner,
             block_rows):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(0)
+    i = ib_ref[t]
     dtype = np.dtype(o_ref.dtype)
     ident = dtype.type(combiner.ident_for(dtype))  # no dtype promotion
     reduce = _REDUCE[combiner.name]
 
-    @pl.when(j == 0)
+    @pl.when((t == 0) | (i != ib_ref[jnp.maximum(t - 1, 0)]))
     def _init():
         o_ref[...] = jnp.full_like(o_ref, ident)
 
-    @pl.when(j < nc_ref[i])
+    @pl.when(ic_ref[t] >= 0)
     def _compute():
         rel = seg_ref[...] - i * block_rows  # (R, L) row within the block
         rows = jax.lax.broadcasted_iota(
@@ -76,28 +86,28 @@ def _kernel(cs_ref, nc_ref, seg_ref, vals_ref, o_ref, *, combiner,
 def segment_combine_pallas(
     vals,
     seg_ids,
-    chunk_start,
-    num_chunks,
+    item_block,
+    item_chunk,
     *,
     num_segments: int,
     combiner,
     block_rows: int = 128,
     block_edges: int = 512,
-    max_chunks: int,
     interpret: bool = True,
 ):
-    """Block-CSR segment combine.
+    """Block-CSR segment combine over a flat work list.
 
     Args:
       vals: (E_pad, D) values, sorted by segment; padded entries must have
         seg_ids >= num_segments (any value).
       seg_ids: (E_pad,) int32 sorted segment ids.
-      chunk_start: (NB,) int32 first covering chunk per row block.
-      num_chunks: (NB,) int32 number of covering chunks per row block.
+      item_block: (T,) int32 row block of each work item, nondecreasing.
+      item_chunk: (T,) int32 chunk of each work item, ascending within a
+        block; ``~c`` for an item that fetches chunk ``c`` and combines
+        nothing (an empty block's item, or trailing padding).
       num_segments: output rows (padded to a multiple of block_rows).
       combiner: ``sum``, ``min`` or ``max`` (name or Combiner).
       block_edges: chunk length (at most 128, or a multiple of 128).
-      max_chunks: static bound on per-block chunk count (grid dim).
     Returns:
       (num_segments, D) combined values (identity for empty segments).
     """
@@ -112,15 +122,14 @@ def segment_combine_pallas(
     nb = num_segments // block_rows
     ec = E // block_edges
     r, l = chunk_tile(block_edges)
-    grid = (nb, max(int(max_chunks), 1))
 
-    def chunk(i, j, cs_ref, nc_ref):
-        c = cs_ref[i] + jnp.minimum(j, jnp.maximum(nc_ref[i] - 1, 0))
-        return jnp.clip(c, 0, ec - 1)
+    def chunk(t, ib_ref, ic_ref):
+        c = ic_ref[t]
+        return jnp.where(c < 0, ~c, c)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(item_block.shape[0],),
         in_specs=[
             pl.BlockSpec((pl.squeezed, r, l),
                          lambda *a: (chunk(*a), 0, 0)),
@@ -128,7 +137,7 @@ def segment_combine_pallas(
                          lambda *a: (0, chunk(*a), 0, 0)),
         ],
         out_specs=pl.BlockSpec((pl.squeezed, D, block_rows),
-                               lambda i, j, cs, nc: (i, 0, 0)),
+                               lambda t, ib, ic: (ib[t], 0, 0)),
     )
     kernel = functools.partial(_kernel, combiner=combiner,
                                block_rows=block_rows)
@@ -136,10 +145,12 @@ def segment_combine_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, D, block_rows), vals.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
-        jnp.asarray(chunk_start, jnp.int32),
-        jnp.asarray(num_chunks, jnp.int32),
+        jnp.asarray(item_block, jnp.int32),
+        jnp.asarray(item_chunk, jnp.int32),
         jnp.asarray(seg_ids, jnp.int32).reshape(ec, r, l),
         vals.T.reshape(D, ec, r, l),
     )

@@ -405,33 +405,43 @@ def test_route_kernel_path_matches_reference():
 
 
 def test_plan_chunks_mirrors_kernel_padding():
-    """ops.plan_chunks builds host tables against the kernel's padded
-    view; if the two paddings ever desynchronize the kernel combines the
-    wrong chunks. Sweep block sizes that force max_chunks > 1."""
+    """ops.plan_chunks builds the host work list against the kernel's
+    padded view; if the two paddings ever desynchronize the kernel
+    combines the wrong chunks. Sweep block sizes that force several
+    chunks per block, with the exact list and one padded past it."""
     rng = np.random.default_rng(21)
     n, e = 100, 1500
     seg_np = np.sort(rng.integers(0, n, e)).astype(np.int32)
     vals = jnp.asarray(rng.normal(size=(e, 2)).astype(np.float32))
     want = kref.segment_combine_ref(vals, jnp.asarray(seg_np), n, "sum")
     for br, be in [(8, 64), (32, 128), (128, 512)]:
-        cs, nc, mx = kops.plan_chunks(seg_np, n, br, be)
-        assert mx >= 1
-        got = kops.segment_combine(
-            vals, jnp.asarray(seg_np), n, "sum", use_kernel=True,
-            assume_sorted=True, block_rows=br, block_edges=be,
-            chunk_plan=(jnp.asarray(cs), jnp.asarray(nc), mx))
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
+        bounds = kops.plan_chunks(seg_np, n, br, be)
+        blk, chunk = kops.build_work_list(*bounds)
+        assert np.bincount(blk[chunk >= 0]).max() > 1
+        for items in (len(blk), len(blk) + 5):
+            got = kops.segment_combine(
+                vals, jnp.asarray(seg_np), n, "sum", use_kernel=True,
+                assume_sorted=True, block_rows=br, block_edges=be,
+                work_list=kops.build_work_list(*bounds, items))
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-5, atol=1e-5)
 
 
 def test_scatter_plan_chunk_tables_drive_the_kernel():
     """The default-on-TPU path: segment_combine through a built
-    ScatterPlan's autotuned chunk tables == the reference, per worker."""
+    ScatterPlan's work list == the reference, per worker; the plan
+    reports the kernel's grid and its active items."""
     from repro.graph import generators as gen, pgraph
 
     g = gen.rmat(8, edge_factor=8, seed=7).symmetrized()
     pg = pgraph.partition_graph(g, W, "random", build=("scatter_out",))
     plan = pg.scatter_out
+    nb = -(-plan.u_cap // plan.block_rows)
+    ec = -(-plan.e_cap // plan.block_edges)
+    assert plan.item_block.shape == (W, plan.grid_steps)
+    assert plan.grid_steps <= nb + ec
+    active = plan.active_items
+    assert active.shape == (W,) and (active <= plan.grid_steps).all()
     rng = np.random.default_rng(8)
     for w in range(W):
         seg = plan.edge_seg[w]
@@ -441,10 +451,12 @@ def test_scatter_plan_chunk_tables_drive_the_kernel():
             vals, seg, plan.u_cap, "min", use_kernel=True,
             assume_sorted=True, block_rows=plan.block_rows,
             block_edges=plan.block_edges,
-            chunk_plan=(plan.chunk_start[w], plan.chunk_count[w],
-                        plan.max_chunks))
+            work_list=(plan.item_block[w], plan.item_chunk[w]))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+        _, chunk = kops.build_work_list(*kops.plan_chunks(
+            np.asarray(seg), plan.u_cap, plan.block_rows, plan.block_edges))
+        assert active[w] == (chunk >= 0).sum()
 
 
 # ---------------------------------------------------------------------------

@@ -157,6 +157,80 @@ def test_segment_combine_kernel_rejects_unreducible_combiner():
                             assume_sorted=True)
 
 
+def _skewed_segments(seed):
+    """A Graph500-like hub: one segment holds 1500 edges (about 12 chunks
+    of 128, so one row block of 8 covers many chunks), most row blocks
+    hold none, and light blocks sit at both ends."""
+    rng = np.random.default_rng(seed)
+    seg = np.concatenate([rng.integers(0, 60, 200), np.full(1500, 1003),
+                          rng.integers(1900, 2000, 300)])
+    return np.sort(seg).astype(np.int32), 2000
+
+
+@pytest.mark.parametrize("planned", [True, False], ids=["plan", "device"])
+@pytest.mark.parametrize("combiner,dtype", [
+    ("min", np.int32), ("max", np.int32), ("sum", np.int32),
+    ("sum", np.float32)], ids=["int32-min", "int32-max", "int32-sum",
+                               "f32-sum"])
+def test_segment_combine_kernel_skewed_work_list(combiner, dtype, planned):
+    """The work list on a skewed edge array equals the reference exactly
+    (the f32 sum adds small integers), with the host plan's list and
+    with the one built on the device."""
+    seg, n = _skewed_segments(3)
+    br, be = 8, 128
+    rng = np.random.default_rng(4)
+    vals = jnp.asarray(rng.integers(-50, 50, (len(seg), 2)).astype(dtype))
+    work = None
+    if planned:
+        work = ops.build_work_list(*ops.plan_chunks(seg, n, br, be))
+        blk, chunk = work
+        assert np.bincount(blk[chunk >= 0]).max() >= 12  # the hub block
+        assert (chunk < 0).sum() > len(blk) // 2  # mostly empty blocks
+    got = ops.segment_combine(vals, jnp.asarray(seg), n, combiner,
+                              use_kernel=True, assume_sorted=True,
+                              block_rows=br, block_edges=be, work_list=work)
+    want = ref.segment_combine_ref(vals, jnp.asarray(seg), n, combiner)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@settings(max_examples=40, deadline=None)
+@given(
+    e=st.integers(1, 3000),
+    n=st.integers(1, 3000),
+    hub=st.floats(0.0, 0.9),
+    br=st.sampled_from([8, 32, 128]),
+    be=st.sampled_from([64, 128, 512]),
+    seed=st.integers(0, 2**31 - 1),
+)
+def test_work_list_property(e, n, hub, br, be, seed):
+    """Every plan's work list stays within NB + EC items, lists every row
+    block, runs in block order, and gives each block exactly its
+    covering chunks, ascending; the rest fetch a chunk and combine
+    nothing."""
+    rng = np.random.default_rng(seed)
+    seg = rng.integers(0, n + 2, e)  # ids >= n are dropped
+    seg[: int(hub * e)] = rng.integers(0, n)
+    seg = np.sort(seg).astype(np.int32)
+    start, count, ec = ops.plan_chunks(seg, n, br, be)
+    blk, chunk = ops.build_work_list(start, count, ec)
+    nb = len(start)
+    assert len(blk) <= nb + ec
+    assert np.array_equal(np.unique(blk), np.arange(nb))
+    assert (np.diff(blk) >= 0).all()
+    fetched = np.where(chunk < 0, ~chunk, chunk)
+    assert ((fetched >= 0) & (fetched < ec)).all()
+    assert (np.diff(fetched) >= 0).all()  # no chunk is fetched twice
+    padded = np.where(seg < n, seg, nb * br)  # the kernel's view
+    for b in range(nb):
+        edges = np.flatnonzero(padded // br == b)
+        want = (np.arange(edges[0] // be, edges[-1] // be + 1)
+                if len(edges) else [])
+        np.testing.assert_array_equal(chunk[(blk == b) & (chunk >= 0)], want)
+    longer = ops.build_work_list(start, count, ec, len(blk) + 3)
+    assert (longer[1][len(blk):] < 0).all()
+    assert (longer[0][len(blk):] == blk[-1]).all()
+
+
 BUCKET_CASES = {
     # id: (m, num_buckets, block_msgs, lanes Q or None)
     "single-bucket": (700, 1, 1024, None),

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.lib import xla_client
 
 from repro.kernels import bucket_route as kbucket
 from repro.kernels import ops as kops
@@ -83,20 +84,34 @@ def test_bucket_ranks_lanes_compiles_for_v5e(one_chip):
     ("min", jnp.int32, 8),
 ], ids=["int32-min", "f32-sum", "int32-min-d8"])
 def test_segment_combine_compiles_for_v5e(one_chip, combiner, dtype, d):
-    block_rows, block_edges = kops.autotune_block_sizes(N, E)
-    nb = N // block_rows
+    """The work-list kernel at the chip's sizes, with its grid at the
+    bound NB + EC; the compiled call keeps the operand signature that
+    ``benchmarks/chip/kernel_cost.py`` reads its roofline share from."""
+    from benchmarks.chip import kernel_cost
 
-    def combine(vals, seg, cs, nc):
+    block_rows, block_edges = kops.autotune_block_sizes(N, E)
+    items = N // block_rows + E // block_edges
+
+    def combine(vals, seg, item_block, item_chunk):
         return kseg.segment_combine_pallas(
-            vals, seg, cs, nc, num_segments=N, combiner=combiner,
-            block_rows=block_rows, block_edges=block_edges, max_chunks=4,
-            interpret=False)
+            vals, seg, item_block, item_chunk, num_segments=N,
+            combiner=combiner, block_rows=block_rows,
+            block_edges=block_edges, interpret=False)
 
     exe = _compile(combine, _spec(one_chip, (E, d), dtype),
                    _spec(one_chip, (E,), jnp.int32),
-                   _spec(one_chip, (nb,), jnp.int32),
-                   _spec(one_chip, (nb,), jnp.int32))
-    assert "tpu_custom_call" in exe.as_text()
+                   _spec(one_chip, (items,), jnp.int32),
+                   _spec(one_chip, (items,), jnp.int32))
+    # the trace names a device op by its HLO text with operand shapes
+    options = xla_client._xla.HloPrintOptions.short_parsable()
+    options.print_operand_shape = True
+    hlo = exe.runtime_executable().hlo_modules()[0].to_string(options)
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    costs = [kernel_cost.segment_combine(*kernel_cost.signature(line))
+             for line in calls]
+    moved = E * 4 + E * d * 4 + N * d * 4  # ids, values, one output row
+    assert (moved, E * d) in costs
 
 
 @pytest.mark.parametrize("key,serve", [("sv:composed", False),
