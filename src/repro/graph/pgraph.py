@@ -134,6 +134,22 @@ class ScatterPlan:
             return np.zeros(self.num_workers, np.int64)
         return (np.asarray(self.item_chunk) >= 0).sum(axis=-1)
 
+    @property
+    def window_steps(self) -> np.ndarray:
+        """(W, 2) body work of each worker's segment-combine kernel call:
+        the 8-row windows its narrow lane rows take, and its lane rows
+        that take the block-wide sweep (``kops.window_steps``; host
+        read-back)."""
+        if self.item_chunk is None:
+            return np.zeros((self.num_workers, 2), np.int64)
+        from repro.kernels import ops as kops
+
+        seg = np.asarray(self.edge_seg)
+        blk, chunk = np.asarray(self.item_block), np.asarray(self.item_chunk)
+        return np.array([kops.window_steps(
+            seg[w], self.u_cap, self.block_rows, self.block_edges, blk[w],
+            chunk[w]) for w in range(self.num_workers)], np.int64)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass

@@ -20,11 +20,10 @@ def chunk_tile(chunk: int):
     return chunk // lanes, lanes
 
 
-def col_to_row(col, fill=0, reduce=jnp.sum):
-    """(N, 1) column -> (1, N) row, exactly: a diagonal select (``fill``
-    elsewhere) reduced down the sublanes — one real value per column, so
-    any reduction with ``fill`` as its identity returns it unchanged."""
+def col_to_row(col):
+    """(N, 1) column -> (1, N) row, exactly: a diagonal select (0
+    elsewhere) summed down the sublanes — one real value per column."""
     n = col.shape[0]
     diag = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
             == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
-    return reduce(jnp.where(diag, col, fill), axis=0, keepdims=True)
+    return jnp.sum(jnp.where(diag, col, 0), axis=0, keepdims=True)

@@ -33,6 +33,7 @@ from repro.core import combiners as cb
 from repro.kernels import bucket_route as kbucket
 from repro.kernels import ref as kref
 from repro.kernels import segment_combine as kseg
+from repro.kernels.layout import chunk_tile
 from repro.pregel import tracing
 
 #: the kernel-vs-reference knob (explicit > use_kernel_scope >
@@ -137,21 +138,52 @@ def build_work_list(start, count, num_chunks, n_items=None, xp=np):
             xp.where(real, chunk, ~chunk).astype(xp.int32))
 
 
-def plan_chunks(seg_ids_np, num_segments, block_rows, block_edges):
-    """:func:`chunk_bounds` on the host against the *kernel's* padded view
-    of the inputs, as :func:`segment_combine` builds it (entries outside
-    ``[0, num_segments)`` map to the padded row bound, the edge axis is
-    padded to a ``block_edges`` multiple), so :func:`build_work_list` of
-    the result can be passed as its ``work_list``. Returns (start, count,
-    num_chunks)."""
+def _kernel_view(seg_ids_np, num_segments, block_rows, block_edges):
+    """The sorted ids as :func:`segment_combine` hands them to the kernel:
+    entries outside ``[0, num_segments)`` map to the padded row bound,
+    and the edge axis is padded to a ``block_edges`` multiple. Returns
+    (ids, padded row count)."""
     seg = np.asarray(seg_ids_np)
     n_pad = _round_up(max(num_segments, 1), block_rows)
     e_pad = _round_up(max(len(seg), 1), block_edges)
     seg = np.where((seg < 0) | (seg >= num_segments), n_pad, seg)
     seg = np.concatenate([seg, np.full(e_pad - len(seg), n_pad, seg.dtype)])
+    return seg, n_pad
+
+
+def plan_chunks(seg_ids_np, num_segments, block_rows, block_edges):
+    """:func:`chunk_bounds` on the host against the *kernel's* padded view
+    of the inputs, as :func:`segment_combine` builds it, so
+    :func:`build_work_list` of the result can be passed as its
+    ``work_list``. Returns (start, count, num_chunks)."""
+    seg, n_pad = _kernel_view(seg_ids_np, num_segments, block_rows,
+                              block_edges)
     start, count = chunk_bounds(seg, n_pad // block_rows, block_rows,
                                 block_edges)
-    return start, count, e_pad // block_edges
+    return start, count, len(seg) // block_edges
+
+
+def window_steps(seg_ids_np, num_segments, block_rows, block_edges,
+                 item_block, item_chunk):
+    """What one kernel call's body does over this work list (host count,
+    numpy): (windows, wide_rows) — the ``WINDOW``-row windows its narrow
+    lane rows take, and the lane rows that take the block-wide sweep
+    instead (``repro.kernels.segment_combine``). A lane row spans the
+    windows from its first id to its last, clipped to the item's block;
+    one whose ids all lie outside the block counts in neither."""
+    seg, _ = _kernel_view(seg_ids_np, num_segments, block_rows, block_edges)
+    r, l = chunk_tile(block_edges)
+    rows = seg.reshape(-1, r, l)
+    item_block, item_chunk = np.asarray(item_block), np.asarray(item_chunk)
+    real = item_chunk >= 0
+    base = (item_block[real] * block_rows)[:, None]  # (items, 1)
+    first = rows[item_chunk[real], :, 0] - base      # (items, R)
+    final = rows[item_chunk[real], :, -1] - base
+    hit = (first < block_rows) & (final >= 0)
+    span = (np.minimum(final, block_rows - 1) // kseg.WINDOW
+            - np.maximum(first, 0) // kseg.WINDOW + 1)
+    wide = hit & (span > kseg.WIDE_WINDOWS)
+    return int(np.where(hit & ~wide, span, 0).sum()), int(wide.sum())
 
 
 # ---------------------------------------------------------------------------

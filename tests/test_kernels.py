@@ -11,6 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import combiners as cb
 from repro.kernels import ops, ref
+from repro.kernels import segment_combine as kseg
 
 COMBINERS = ["sum", "min", "max"]
 
@@ -191,6 +192,90 @@ def test_segment_combine_kernel_skewed_work_list(combiner, dtype, planned):
                               block_rows=br, block_edges=be, work_list=work)
     want = ref.segment_combine_ref(vals, jnp.asarray(seg), n, combiner)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _lane_rows(br, case):
+    """Sorted ids, one 128-edge lane row per list entry, built so that a
+    named path of the kernel body runs; and the rows of output."""
+    rng = np.random.default_rng(br)
+    wide = kseg.WIDE_WINDOWS * kseg.WINDOW
+    fill = lambda lo, hi: np.sort(np.r_[lo, hi - 1, rng.integers(lo, hi, 126)])
+    n = 3 * br
+    rows = {
+        "one-window": [fill(16, 24), fill(24, 32)],
+        "several-windows": [fill(8 + 3, 8 + wide - 4)],
+        "wide": [np.arange(128) + br // 2 - 64],
+        "straddle": [fill(br - 20, br + 20)],
+        "all-padding": [fill(0, 8), np.full(128, n + 7)],
+        "empty-block": [fill(0, 16), fill(2 * br, 2 * br + 16)],
+        "last-window": [fill(br - 8, br), fill(n - 8, n)],
+    }[case]
+    return np.concatenate(rows).astype(np.int32), n
+
+
+BODY_CASES = ["one-window", "several-windows", "wide", "straddle",
+              "all-padding", "empty-block", "last-window"]
+
+
+@pytest.mark.parametrize("br", [128, 256])
+@pytest.mark.parametrize("case", BODY_CASES)
+def test_segment_combine_kernel_body_paths(case, br):
+    """Each path of the body (narrow lane rows in one or several windows,
+    the block-wide sweep, a row across two blocks, a row of padding, an
+    empty block, the block's last window) equals the reference exactly:
+    integer min/max/sum and f32 sums of small integers, D = 1 and 2."""
+    seg, n = _lane_rows(br, case)
+    be = 256
+    work = ops.build_work_list(*ops.plan_chunks(seg, n, br, be))
+    windows, wide_rows = ops.window_steps(seg, n, br, be, *work)
+    assert (wide_rows > 0) == (case == "wide")
+    if case == "several-windows":
+        assert windows == kseg.WIDE_WINDOWS
+    rng = np.random.default_rng(len(case))
+    for combiner, dtype in [("min", np.int32), ("max", np.int32),
+                            ("sum", np.int32), ("sum", np.float32)]:
+        for d in (1, 2):
+            vals = jnp.asarray(
+                rng.integers(-50, 50, (len(seg), d)).astype(dtype))
+            got = ops.segment_combine(vals, jnp.asarray(seg), n, combiner,
+                                      use_kernel=True, assume_sorted=True,
+                                      block_rows=br, block_edges=be,
+                                      work_list=work)
+            want = ref.segment_combine_ref(vals, jnp.asarray(seg), n,
+                                           combiner)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_window_steps_counts_the_body_work():
+    """``ScatterPlan.window_steps`` equals a lane-row-by-lane-row count of
+    the body's windows and wide rows on a skewed plan, at the plan's own
+    block sizes."""
+    from repro.graph import generators as gen, pgraph
+
+    g = gen.rmat(10, edge_factor=8, seed=1).symmetrized()
+    plan = pgraph.partition_graph(g, 2, "random",
+                                  build=("scatter_out",)).scatter_out
+    br, be = plan.block_rows, plan.block_edges
+    n_pad = -(-plan.u_cap // br) * br
+    got = plan.window_steps
+    assert got.shape == (2, 2) and (got > 0).all()  # both paths taken
+    for w in range(plan.num_workers):
+        seg = [s if s < plan.u_cap else n_pad for s in plan.edge_seg[w]]
+        seg += [n_pad] * (-len(seg) % be)
+        windows = wide = 0
+        for b, c in zip(plan.item_block[w], plan.item_chunk[w]):
+            for start in range(c * be, (c + 1) * be, 128) if c >= 0 else ():
+                # the rows from the lane row's first id to its last,
+                # clipped to the block
+                lo = max(seg[start] - b * br, 0)
+                hi = min(seg[start + 127] - b * br, br - 1)
+                if lo <= hi:
+                    span = hi // 8 - lo // 8 + 1
+                    if span > kseg.WIDE_WINDOWS:
+                        wide += 1
+                    else:
+                        windows += span
+        assert tuple(got[w]) == (windows, wide)
 
 
 @settings(max_examples=40, deadline=None)

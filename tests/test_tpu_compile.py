@@ -174,6 +174,33 @@ def test_segment_combine_work_list_fits_the_chip_at_scale_24(one_chip):
     assert "tpu_custom_call" in exe.as_text()
 
 
+def test_segment_combine_compiles_at_the_4chip_plans_blocks(one_chip):
+    """A worker's plan in the 4-chip cell (about 2**27 edges onto 2**23
+    destinations) takes 256-row blocks of 2,048-edge chunks, where the
+    block-wide sweep covers 32 windows: the body compiles there, and the
+    call keeps the signature ``benchmarks/chip/kernel_cost.py`` reads."""
+    from benchmarks.chip import kernel_cost
+
+    e, u = 1 << 27, 1 << 23
+    block_rows, block_edges = kops.autotune_block_sizes(u, e)
+    assert (block_rows, block_edges) == (256, 2048)
+    items = u // block_rows + e // block_edges
+    exe = _compile(
+        lambda v, s, ib, ic: kseg.segment_combine_pallas(
+            v, s, ib, ic, num_segments=u, combiner="min",
+            block_rows=block_rows, block_edges=block_edges,
+            interpret=False),
+        _spec(one_chip, (e, 1), jnp.int32), _spec(one_chip, (e,), jnp.int32),
+        _spec(one_chip, (items,), jnp.int32),
+        _spec(one_chip, (items,), jnp.int32))
+    options = xla_client._xla.HloPrintOptions.short_parsable()
+    options.print_operand_shape = True
+    hlo = exe.runtime_executable().hlo_modules()[0].to_string(options)
+    costs = [kernel_cost.segment_combine(*kernel_cost.signature(line))
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert (e * 4 + e * 4 + u * 4, e) in costs
+
+
 def test_mesh_loop_collectives_lie_under_their_scopes(topo, monkeypatch):
     """sv:composed's fused loop on a described 2x2 mesh, one worker per
     chip: every all-to-all lies under an ``exchange`` scope, and every
