@@ -4,7 +4,7 @@
 
 Default scale is CPU-friendly (~8k vertices / ~100k edges per graph);
 --full uses 4x larger graphs. Emits the per-table results as text plus a
-final CSV block, and (if results/dryrun exists) the roofline table.
+final CSV block.
 """
 from __future__ import annotations
 
@@ -61,11 +61,6 @@ def main() -> None:
     if args.csv:
         with open(args.csv, "w") as f:
             common.print_csv(f)
-
-    if os.path.isdir("results/dryrun"):
-        print("\n== Roofline (from dry-run artifacts) ==")
-        from benchmarks import roofline
-        roofline.main()
 
 
 if __name__ == "__main__":

@@ -265,16 +265,6 @@ def segment_combine(
     return out[:, 0] if squeeze else out
 
 
-@tracing.scope(tracing.COMBINE)
-def gather_segment_combine(
-    src_vals, edge_src, seg_ids, num_segments, combiner, **kw
-):
-    """Fused gather + segment combine (SpMV-style). Gather is left to XLA
-    (it fuses with the kernel's input stream); the reduce uses the kernel."""
-    vals = jnp.asarray(src_vals)[jnp.asarray(edge_src, jnp.int32)]
-    return segment_combine(vals, seg_ids, num_segments, combiner, **kw)
-
-
 # ---------------------------------------------------------------------------
 # bucket ranks (routing data plane)
 # ---------------------------------------------------------------------------

@@ -76,17 +76,3 @@ def bucket_ranks_lanes_ref(keys, lanes, num_buckets):
         jnp.asarray(lanes, jnp.int32), keys, num_buckets + 1
     )[:num_buckets]
     return rank, counts, lane_counts
-
-
-def gather_segment_combine_ref(src_vals, edge_src, seg_ids, num_segments, combiner):
-    """Fused gather + segment reduction oracle (the SpMV-style hot loop).
-
-    Args:
-      src_vals: (N_src, D) per-source values.
-      edge_src: (E,) int32 source index per edge (padded edges may point
-        anywhere valid; they must carry seg_ids >= num_segments).
-      seg_ids: (E,) int32 destination segment per edge.
-    """
-    combiner = cb.get(combiner)
-    vals = src_vals[jnp.asarray(edge_src, jnp.int32)]
-    return combiner.segment_reduce(vals, jnp.asarray(seg_ids, jnp.int32), num_segments)

@@ -11,10 +11,10 @@ One vocabulary, two carriers, and no switch:
     per channel. Inside a channel the work splits into :data:`PHASES`:
     ``route`` (owners, keys, bucket ranks, slots, pack, dedup),
     ``exchange`` (each collective and the unpack after it) and
-    ``combine`` (``kops.segment_combine`` and ``gather_segment_combine``,
-    the kernel and the reference). The runtime puts every instruction of
-    a compiled loop under :data:`SUPERSTEP`, the program's step function
-    under :data:`COMPUTE` inside it (so a channel's path reads
+    ``combine`` (``kops.segment_combine``, the kernel and the
+    reference). The runtime puts every instruction of a compiled loop
+    under :data:`SUPERSTEP`, the program's step function under
+    :data:`COMPUTE` inside it (so a channel's path reads
     ``superstep/compute/sv/jump/route``), and the halt vote and stat
     reductions under :data:`VOTE` (``superstep/vote``).
   - **Host spans** (``jax.profiler.TraceAnnotation``) name what the host
